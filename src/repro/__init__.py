@@ -1,7 +1,3 @@
 """repro: JAX/TPU expert-parallel training & inference framework reproducing
 "NCCL EP: Towards a Unified Expert Parallel Communication API for NCCL"."""
-from repro import compat as _compat
-
-_compat.install()
-
 __version__ = "0.1.0"

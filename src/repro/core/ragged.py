@@ -23,8 +23,7 @@ from repro.core import slots as S
 
 
 def ragged_supported() -> bool:
-    return (hasattr(jax.lax, "ragged_all_to_all")
-            and jax.default_backend() == "tpu")
+    return jax.default_backend() == "tpu"
 
 
 def ll_dispatch_ragged(group: EpGroup, handle: EpHandle, x: jax.Array):

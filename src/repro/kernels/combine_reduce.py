@@ -20,6 +20,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.shapes import out_struct
+
 
 def _kernel(y_ref, w_ref, o_ref):
     # y_ref: [bt, K, bh]; w_ref: [bt, K]; o_ref: [bt, bh]
@@ -43,7 +45,8 @@ def combine_reduce(y: jax.Array, w: jax.Array, *, bt: int = 8, bh: int = 512,
     out_dt = y.dtype if y.dtype in (jnp.bfloat16, jnp.float32, jnp.float16) else jnp.bfloat16
     return pl.pallas_call(
         _kernel,
-        out_shape=jax.ShapeDtypeStruct((T, H), out_dt),
+        name="combine_reduce",
+        out_shape=out_struct((T, H), out_dt, y, w),
         grid=(T // bt, H // bh),
         in_specs=[
             pl.BlockSpec((bt, K, bh), lambda i, j: (i, 0, j)),

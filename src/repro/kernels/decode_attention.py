@@ -14,6 +14,13 @@ aiter's ``mla_decode_fwd`` (SNIPPETS.md Snippet 1):
     written at the last page.
   stage 2 — grid (B,): LSE-weighted reduction across splits.
 
+A page enters VMEM as one [page*Hkv, d] tile (the pool viewed as
+[P+1, page*Hkv, d]), and every query head scores every row of it: one 2-D
+matmul, masked so each head keeps only its own kv head's rows. That is
+Hkv times the needed MXU work, on a step bound by reading the pages; it
+keeps the kernel free of the in-kernel reshapes and batched dots Mosaic
+does not lower.
+
 Determinism contract (what makes page recycling safe): masked positions
 contribute an EXACT zero — ``p = where(pos < kv_len, exp(s - m), 0)``, never
 exp underflow — so garbage in recycled or pad pages cannot perturb a live
@@ -32,14 +39,17 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.shapes import out_struct
 
 NEG_INF = -1e30
 
 
-def _stage1_kernel(tbl_ref, lens_ref, q_ref, k_ref, *rest,
-                   page, pps, Hkv, G, dv, scale, share_kv):
+def _stage1_kernel(tbl_ref, lens_ref, tok_ref, q_ref, k_ref, *rest,
+                   page, pps, dv, scale, share_kv):
     if share_kv:
         v_ref = None
         o_ref, lse_ref, m_ref, l_ref, acc_ref = rest
@@ -63,26 +73,26 @@ def _stage1_kernel(tbl_ref, lens_ref, q_ref, k_ref, *rest,
     # emits the exact empty values)
     @pl.when(base < kv_len)
     def _compute():
-        q = q_ref[0].astype(jnp.float32).reshape(Hkv, G, -1)   # [Hkv, G, dk]
-        k = k_ref[0].astype(jnp.float32)                        # [page, Hkv, dk]
-        v = k[..., :dv] if share_kv else v_ref[0].astype(jnp.float32)
+        q = q_ref[0].astype(jnp.float32)                        # [Hq, dk]
+        k = k_ref[0].astype(jnp.float32)                        # [page*Hkv, dk]
+        v = k[:, :dv] if share_kv else v_ref[0].astype(jnp.float32)
         sc = jax.lax.dot_general(
-            q, k, (((2,), (2,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32) * scale         # [Hkv, G, page]
-        pos = base + jax.lax.broadcasted_iota(jnp.int32, (Hkv, G, page), 2)
-        valid = pos < kv_len
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale         # [Hq, page*Hkv]
+        # a column is live for a row when it is the row's kv head and its
+        # token is inside the request (tok_ref says which token, or a
+        # sentinel past every length for another head's column)
+        valid = base + tok_ref[...] < kv_len
         sc = jnp.where(valid, sc, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, sc.max(axis=-1))
+        m_prev = m_ref[...]                                     # [Hq, 1]
+        m_new = jnp.maximum(m_prev, sc.max(axis=1, keepdims=True))
         # exact zero for masked positions — recycled-page garbage and pad
         # pages contribute nothing, not just "something tiny"
-        p = jnp.where(valid, jnp.exp(sc - m_new[..., None]), 0.0)
+        p = jnp.where(valid, jnp.exp(sc - m_new), 0.0)
         corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + p.sum(axis=-1)
-        ctx = jax.lax.dot_general(
-            p, v, (((2,), (0,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32)                 # [Hkv, G, dv]
-        acc_ref[...] = acc_ref[...] * corr[..., None] + ctx
+        l_ref[...] = l_ref[...] * corr + p.sum(axis=1, keepdims=True)
+        ctx = jnp.dot(p, v, preferred_element_type=jnp.float32)  # [Hq, dv]
+        acc_ref[...] = acc_ref[...] * corr + ctx
         m_ref[...] = m_new
 
     @pl.when(j == pps - 1)
@@ -90,21 +100,29 @@ def _stage1_kernel(tbl_ref, lens_ref, q_ref, k_ref, *rest,
         l = l_ref[...]
         live = l > 0
         safe = jnp.where(live, l, 1.0)
-        o = jnp.where(live[..., None], acc_ref[...] / safe[..., None], 0.0)
-        lse = jnp.where(live, m_ref[...] + jnp.log(safe), NEG_INF)
-        o_ref[0, 0] = o.reshape(Hkv * G, dv)
-        lse_ref[0, 0] = lse.reshape(Hkv * G)
+        o_ref[0, 0] = jnp.where(live, acc_ref[...] / safe, 0.0)
+        lse_ref[0, 0] = jnp.where(live, m_ref[...] + jnp.log(safe), NEG_INF)
 
 
 def _stage2_kernel(o_ref, lse_ref, out_ref):
     o = o_ref[0]                                                # [S, Hq, dv]
-    lse = lse_ref[0]                                            # [S, Hq]
-    mx = lse.max(axis=0)                                        # [Hq]
-    w = jnp.where(lse > NEG_INF / 2, jnp.exp(lse - mx[None, :]), 0.0)
-    denom = w.sum(axis=0)                                       # [Hq]
-    out = (w[..., None] * o).sum(axis=0)                        # [Hq, dv]
+    lse = lse_ref[0]                                            # [S, Hq, 1]
+    mx = lse.max(axis=0)                                        # [Hq, 1]
+    w = jnp.where(lse > NEG_INF / 2, jnp.exp(lse - mx[None]), 0.0)
+    denom = w.sum(axis=0)                                       # [Hq, 1]
+    out = (w * o).sum(axis=0)                                   # [Hq, dv]
     safe = jnp.where(denom > 0, denom, 1.0)
-    out_ref[0] = jnp.where((denom > 0)[:, None], out / safe[:, None], 0.0)
+    out_ref[0] = jnp.where(denom > 0, out / safe, 0.0)
+
+
+def _token_of_column(Hq: int, Hkv: int, page: int) -> np.ndarray:
+    """[Hq, page*Hkv] int32: for query head r and pool column c (token
+    c // Hkv, kv head c % Hkv), the token offset inside the page when c is
+    r's kv head, else a sentinel larger than any KV length."""
+    G = Hq // Hkv
+    c = np.arange(page * Hkv)
+    own = (c % Hkv)[None, :] == (np.arange(Hq) // G)[:, None]
+    return np.where(own, c // Hkv, 2 ** 30).astype(np.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "num_kv_splits", "dv",
@@ -123,7 +141,6 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
     B, max_pages = kv_indices.shape
     page, Hkv, dk = k_pages.shape[1:]
     Hq = q.shape[1]
-    G = Hq // Hkv
     S = num_kv_splits
     assert max_pages % S == 0, (max_pages, S)
     pps = max_pages // S
@@ -135,51 +152,59 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
 
     flat_tbl = kv_indices.reshape(-1).astype(jnp.int32)
     lens = kv_lens.astype(jnp.int32)
+    tok = jnp.asarray(_token_of_column(Hq, Hkv, page))
 
-    kern = functools.partial(_stage1_kernel, page=page, pps=pps, Hkv=Hkv,
-                             G=G, dv=dv, scale=scale, share_kv=share_kv)
-    k_spec = pl.BlockSpec(
-        (1, page, Hkv, dk),
-        lambda b, s, j, tbl, lens: (tbl[b * max_pages + s * pps + j], 0, 0, 0))
-    in_specs = [pl.BlockSpec((1, Hq, dk), lambda b, s, j, tbl, lens: (b, 0, 0)),
-                k_spec]
-    operands = [q, k_pages]
+    # pages as [P+1, page*Hkv, width] views: one page is then a block whose
+    # trailing dims are the array's, and K for every head is one 2-D tile
+    rows = page * Hkv
+    kern = functools.partial(_stage1_kernel, page=page, pps=pps, dv=dv,
+                             scale=scale, share_kv=share_kv)
+
+    def page_spec(width):
+        return pl.BlockSpec(
+            (1, rows, width),
+            lambda b, s, j, tbl, lens: (tbl[b * max_pages + s * pps + j], 0, 0))
+
+    in_specs = [pl.BlockSpec((Hq, rows), lambda b, s, j, tbl, lens: (0, 0)),
+                pl.BlockSpec((1, Hq, dk), lambda b, s, j, tbl, lens: (b, 0, 0)),
+                page_spec(dk)]
+    operands = [tok, q, k_pages.reshape(-1, rows, dk)]
     if not share_kv:
-        in_specs.append(pl.BlockSpec(
-            (1, page, Hkv, dv),
-            lambda b, s, j, tbl, lens: (tbl[b * max_pages + s * pps + j],
-                                        0, 0, 0)))
-        operands.append(v_pages)
+        in_specs.append(page_spec(dv))
+        operands.append(v_pages.reshape(-1, rows, dv))
+    ins = (flat_tbl, lens, *operands)
 
     o_parts, lse = pl.pallas_call(
         kern,
-        out_shape=(jax.ShapeDtypeStruct((B, S, Hq, dv), jnp.float32),
-                   jax.ShapeDtypeStruct((B, S, Hq), jnp.float32)),
+        name="paged_decode_stage1",
+        out_shape=(out_struct((B, S, Hq, dv), jnp.float32, *ins),
+                   out_struct((B, S, Hq, 1), jnp.float32, *ins)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(B, S, pps),
             in_specs=in_specs,
             out_specs=(
                 pl.BlockSpec((1, 1, Hq, dv),
                              lambda b, s, j, tbl, lens: (b, s, 0, 0)),
-                pl.BlockSpec((1, 1, Hq),
-                             lambda b, s, j, tbl, lens: (b, s, 0)),
+                pl.BlockSpec((1, 1, Hq, 1),
+                             lambda b, s, j, tbl, lens: (b, s, 0, 0)),
             ),
             scratch_shapes=[
-                pltpu.VMEM((Hkv, G), jnp.float32),
-                pltpu.VMEM((Hkv, G), jnp.float32),
-                pltpu.VMEM((Hkv, G, dv), jnp.float32),
+                pltpu.VMEM((Hq, 1), jnp.float32),
+                pltpu.VMEM((Hq, 1), jnp.float32),
+                pltpu.VMEM((Hq, dv), jnp.float32),
             ],
         ),
         interpret=interpret,
-    )(flat_tbl, lens, *operands)
+    )(*ins)
 
     return pl.pallas_call(
         _stage2_kernel,
-        out_shape=jax.ShapeDtypeStruct((B, Hq, dv), jnp.float32),
+        name="paged_decode_stage2",
+        out_shape=out_struct((B, Hq, dv), jnp.float32, o_parts, lse),
         grid=(B,),
         in_specs=[
             pl.BlockSpec((1, S, Hq, dv), lambda b: (b, 0, 0, 0)),
-            pl.BlockSpec((1, S, Hq), lambda b: (b, 0, 0)),
+            pl.BlockSpec((1, S, Hq, 1), lambda b: (b, 0, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, Hq, dv), lambda b: (b, 0, 0)),
         interpret=interpret,

@@ -9,6 +9,10 @@ its slot needs from HBM into VMEM, quantizes on the VPU, and writes the packed
 send-buffer tile. Empty slots (sentinel) are zero-filled — they map to a
 guaranteed-zero pad row, keeping the index_map branch-free.
 
+Rows travel as [rows, 1, H] views: a (1, 1, H) block equals the full
+trailing dims, which Mosaic's (8, 128) block-tiling rule accepts for any
+single-row gather.
+
 This is the data-movement hot spot of LL dispatch: the fused version touches
 each token row exactly (#destination ranks) times with no intermediate
 materialization of the [T, H] quantized copy.
@@ -22,16 +26,18 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.shapes import out_struct
+
 
 def _kernel_quant(gmap_ref, x_ref, q_ref, s_ref, *, block):
-    # x_ref: [1, H] the gathered token row; outputs: q [1, H] fp8, s [1, H/block]
-    x = x_ref[...].astype(jnp.float32)
+    # x_ref: [1, 1, H] the gathered token row; q [1, 1, H] fp8, s [1, 1, H/block]
+    x = x_ref[0].astype(jnp.float32)
     H = x.shape[-1]
-    g = x.reshape(H // block, block)
-    amax = jnp.max(jnp.abs(g), axis=-1, keepdims=True)
+    g = x.reshape(1, H // block, block)
+    amax = jnp.max(jnp.abs(g), axis=-1)
     scale = jnp.where(amax > 0, amax / 448.0, 1.0)
-    q_ref[...] = (g / scale).reshape(1, H).astype(q_ref.dtype)
-    s_ref[...] = scale.reshape(1, -1).astype(jnp.float32)
+    q_ref[0] = (g / scale[..., None]).reshape(1, H).astype(q_ref.dtype)
+    s_ref[0] = scale
 
 
 def _kernel_copy(gmap_ref, x_ref, o_ref):
@@ -51,19 +57,20 @@ def dispatch_pack(x: jax.Array, gmap: jax.Array, *, quant_block: int | None = No
         out_dtype = x.dtype
     N, C = gmap.shape
     # pad row T is zeros => sentinel slots come out zero
-    xp = jnp.concatenate([x, jnp.zeros((1, H), x.dtype)], axis=0)
+    xp = jnp.concatenate([x, jnp.zeros((1, H), x.dtype)], axis=0)[:, None]
     flat_map = gmap.reshape(-1)
 
     grid = (N * C,)
-    in_specs = [pl.BlockSpec((1, H), lambda i, m_ref: (m_ref[i], 0))]
+    in_specs = [pl.BlockSpec((1, 1, H), lambda i, m_ref: (m_ref[i], 0, 0))]
 
     if quant_block is None:
         out = pl.pallas_call(
             _kernel_copy,
-            out_shape=jax.ShapeDtypeStruct((N * C, H), out_dtype),
+            name="dispatch_pack",
+            out_shape=out_struct((N * C, 1, H), out_dtype, x, gmap),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=1, grid=grid, in_specs=in_specs,
-                out_specs=pl.BlockSpec((1, H), lambda i, m_ref: (i, 0)),
+                out_specs=pl.BlockSpec((1, 1, H), lambda i, m_ref: (i, 0, 0)),
             ),
             interpret=interpret,
         )(flat_map, xp)
@@ -72,15 +79,18 @@ def dispatch_pack(x: jax.Array, gmap: jax.Array, *, quant_block: int | None = No
     kern = functools.partial(_kernel_quant, block=quant_block)
     q, s = pl.pallas_call(
         kern,
+        name="dispatch_pack",
         out_shape=(
-            jax.ShapeDtypeStruct((N * C, H), jnp.float8_e4m3fn),
-            jax.ShapeDtypeStruct((N * C, H // quant_block), jnp.float32),
+            out_struct((N * C, 1, H), jnp.float8_e4m3fn, x, gmap),
+            out_struct((N * C, 1, H // quant_block), jnp.float32,
+                       x, gmap),
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=grid, in_specs=in_specs,
             out_specs=(
-                pl.BlockSpec((1, H), lambda i, m_ref: (i, 0)),
-                pl.BlockSpec((1, H // quant_block), lambda i, m_ref: (i, 0)),
+                pl.BlockSpec((1, 1, H), lambda i, m_ref: (i, 0, 0)),
+                pl.BlockSpec((1, 1, H // quant_block),
+                             lambda i, m_ref: (i, 0, 0)),
             ),
         ),
         interpret=interpret,

@@ -28,6 +28,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.shapes import out_struct
+
 NEG_INF = -1e30
 
 
@@ -98,7 +100,8 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                              window=window, causal=causal)
     return pl.pallas_call(
         kern,
-        out_shape=jax.ShapeDtypeStruct((B, Hq, Sq, d), q.dtype),
+        name="flash_attention",
+        out_shape=out_struct((B, Hq, Sq, d), q.dtype, q, k, v),
         grid=(B, Hq, Sq // bq, nk),
         in_specs=[
             pl.BlockSpec((1, 1, bq, d), lambda b, h, i, j: (b, h, i, 0)),

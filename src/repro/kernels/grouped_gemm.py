@@ -23,6 +23,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.shapes import out_struct
+
 
 def _kernel(counts_ref, x_ref, w_ref, o_ref, acc_ref, *, bm, bk, nk):
     l = pl.program_id(0)
@@ -63,7 +65,8 @@ def grouped_gemm(x: jax.Array, w: jax.Array, counts: jax.Array, *,
     kern = functools.partial(_kernel, bm=bm, bk=bk, nk=nk)
     return pl.pallas_call(
         kern,
-        out_shape=jax.ShapeDtypeStruct((L, A, F), out_dt),
+        name="grouped_gemm",
+        out_shape=out_struct((L, A, F), out_dt, x, w, counts),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(L, A // bm, F // bn, nk),

@@ -16,6 +16,10 @@ This closes the recv half of the one-pass-per-phase invariant: the seed's
 unpack was an XLA gather followed by a separate ``dequantize_fp8`` pass,
 materializing the full gathered fp8 copy in HBM in between. The fused
 version touches each received row exactly once.
+
+Rows travel as [rows, 1, H] views (scales as [rows, 1, H/block]): a
+(1, 1, H) block equals the full trailing dims, which Mosaic's (8, 128)
+block-tiling rule accepts for any single-row gather.
 """
 from __future__ import annotations
 
@@ -26,17 +30,19 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.shapes import out_struct
+
 
 def _kernel_copy(gmap_ref, x_ref, o_ref):
     o_ref[...] = x_ref[...].astype(o_ref.dtype)
 
 
 def _kernel_dequant(gmap_ref, q_ref, s_ref, o_ref, *, block):
-    # q_ref: [1, H] gathered fp8 row; s_ref: [1, H/block] its scales
-    q = q_ref[...].astype(jnp.float32)
+    # q_ref: [1, 1, H] gathered fp8 row; s_ref: [1, 1, H/block] its scales
+    q = q_ref[0].astype(jnp.float32)
     H = q.shape[-1]
-    g = q.reshape(H // block, block)
-    o_ref[...] = (g * s_ref[0][:, None]).reshape(1, H).astype(o_ref.dtype)
+    g = q.reshape(1, H // block, block)
+    o_ref[0] = (g * s_ref[0][..., None]).reshape(1, H).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("out_dtype", "interpret"))
@@ -59,14 +65,17 @@ def recv_unpack(recv: jax.Array, gmap: jax.Array, scales: jax.Array | None = Non
         if out_dtype is None:
             out_dtype = recv.dtype
         # pad row R is zeros => sentinel slots come out zero
-        xp = jnp.concatenate([recv, jnp.zeros((1, H), recv.dtype)], axis=0)
+        xp = jnp.concatenate([recv, jnp.zeros((1, H), recv.dtype)],
+                             axis=0)[:, None]
         out = pl.pallas_call(
             _kernel_copy,
-            out_shape=jax.ShapeDtypeStruct((M, H), out_dtype),
+            name="recv_unpack",
+            out_shape=out_struct((M, 1, H), out_dtype, recv, gmap),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=1, grid=grid,
-                in_specs=[pl.BlockSpec((1, H), lambda i, m_ref: (m_ref[i], 0))],
-                out_specs=pl.BlockSpec((1, H), lambda i, m_ref: (i, 0)),
+                in_specs=[pl.BlockSpec((1, 1, H),
+                                       lambda i, m_ref: (m_ref[i], 0, 0))],
+                out_specs=pl.BlockSpec((1, 1, H), lambda i, m_ref: (i, 0, 0)),
             ),
             interpret=interpret,
         )(flat_map, xp)
@@ -77,20 +86,22 @@ def recv_unpack(recv: jax.Array, gmap: jax.Array, scales: jax.Array | None = Non
     block = H // scales.shape[-1]
     # zero pad rows for payload AND scales: a sentinel slot dequantizes to
     # exactly 0 * 0 = 0, matching the two-pass reference (gathers fill=0)
-    qp = jnp.concatenate([recv, jnp.zeros((1, H), recv.dtype)], axis=0)
+    qp = jnp.concatenate([recv, jnp.zeros((1, H), recv.dtype)], axis=0)[:, None]
     sp = jnp.concatenate([scales, jnp.zeros((1, H // block), scales.dtype)],
-                         axis=0)
+                         axis=0)[:, None]
     kern = functools.partial(_kernel_dequant, block=block)
     out = pl.pallas_call(
         kern,
-        out_shape=jax.ShapeDtypeStruct((M, H), out_dtype),
+        name="recv_unpack",
+        out_shape=out_struct((M, 1, H), out_dtype, recv, gmap, scales),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=grid,
             in_specs=[
-                pl.BlockSpec((1, H), lambda i, m_ref: (m_ref[i], 0)),
-                pl.BlockSpec((1, H // block), lambda i, m_ref: (m_ref[i], 0)),
+                pl.BlockSpec((1, 1, H), lambda i, m_ref: (m_ref[i], 0, 0)),
+                pl.BlockSpec((1, 1, H // block),
+                             lambda i, m_ref: (m_ref[i], 0, 0)),
             ],
-            out_specs=pl.BlockSpec((1, H), lambda i, m_ref: (i, 0)),
+            out_specs=pl.BlockSpec((1, 1, H), lambda i, m_ref: (i, 0, 0)),
         ),
         interpret=interpret,
     )(flat_map, qp, sp)

@@ -11,6 +11,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config, get_smoke
 from repro.launch.train import parse_mesh
 from repro.runtime.server import DecodeServer
@@ -25,6 +26,7 @@ def main():
     ap.add_argument("--gen", type=int, default=32)
     ap.add_argument("--mesh", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_smoke(args.arch) if args.smoke else get_config(args.arch, "decode_32k")
     mesh = parse_mesh(args.mesh)

@@ -12,6 +12,7 @@ import argparse
 
 import jax
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config, get_smoke
 from repro.optim import AdamWConfig
 from repro.runtime.trainer import Trainer, TrainerConfig
@@ -38,6 +39,7 @@ def main():
     ap.add_argument("--ckpt", default=None)
     ap.add_argument("--lr", type=float, default=3e-4)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_smoke(args.arch) if args.smoke else get_config(args.arch, "train_4k")
     mesh = parse_mesh(args.mesh)

@@ -162,8 +162,7 @@ def paged_attention(p, x, cfg: ArchConfig, mesh, pool, page_tbl, kv_lens,
     if a.logit_softcap is not None:
         raise NotImplementedError("paged decode attention does not support "
                                   "logit softcap")
-    from repro.kernels import ops as KOPS
-    from repro.models.kv_pages import write_token
+    from repro.models.kv_pages import decode_attention, write_token
     positions = kv_lens[:, None]                           # [B, 1]
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
     k = jnp.einsum("bsd,dhk->bshk", x, p["wk"])
@@ -178,9 +177,9 @@ def paged_attention(p, x, cfg: ArchConfig, mesh, pool, page_tbl, kv_lens,
     kp = write_token(pool["k"], k[:, 0], page_tbl, kv_lens)
     vp = write_token(pool["v"], v[:, 0], page_tbl, kv_lens)
     eff = kv_lens + active            # just-written token counts iff active
-    out = KOPS.paged_decode_attention(q[:, 0], kp, vp, page_tbl, eff,
-                                      scale=a.head_dim ** -0.5,
-                                      num_kv_splits=num_kv_splits)
+    out = decode_attention(mesh, q[:, 0], kp, vp, page_tbl, eff,
+                           scale=a.head_dim ** -0.5,
+                           num_kv_splits=num_kv_splits)
     out = out.astype(x.dtype)[:, None]                     # [B, 1, Hq, hd]
     y = jnp.einsum("bshk,hkd->bsd", out, p["wo"])
     return y, {"k": kp, "v": vp}

@@ -28,12 +28,15 @@ per layer holding [ckv | k_rope] rows (Hkv == 1) — values are the leading
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
 from repro.models.config import ArchConfig
-from repro.parallel.sharding import ParamSpec
+from repro.parallel.sharding import DEFAULT_RULES, ParamSpec, logical_to_pspec
 
 
 class PagePoolExhausted(RuntimeError):
@@ -131,6 +134,29 @@ def write_token(pool: jax.Array, new: jax.Array, page_tbl: jax.Array,
     page_ids = jnp.take_along_axis(page_tbl, ord_[:, None], axis=1)[:, 0]
     offs = kv_lens % page
     return pool.at[page_ids, offs].set(new.astype(pool.dtype))
+
+
+def decode_attention(mesh, q, k_pages, v_pages, page_tbl, kv_lens, **kw):
+    """``kernels.ops.paged_decode_attention`` on a mesh: XLA cannot
+    partition a Pallas kernel, so under a mesh each device runs it over its
+    own share of the requests (the batch axes of the sharding rules)
+    against the whole page pool. Pools sharded over a "model" axis are
+    gathered first. Without a mesh it is the plain call."""
+    from repro.kernels import ops as KOPS
+    attend = functools.partial(KOPS.paged_decode_attention, **kw)
+    if v_pages is None:
+        attend = functools.partial(attend, v_pages=None)
+        pools = (k_pages,)
+    else:
+        pools = (k_pages, v_pages)
+    if mesh is None or mesh.empty:
+        return attend(q, *pools, kv_indices=page_tbl, kv_lens=kv_lens)
+    rows = logical_to_pspec(ParamSpec(q.shape[:1], axes=("batch",)), mesh,
+                            DEFAULT_RULES)
+    return jax.shard_map(
+        lambda q, t, n, *pools: attend(q, *pools, kv_indices=t, kv_lens=n),
+        mesh=mesh, in_specs=(rows, rows, rows) + (P(),) * len(pools),
+        out_specs=rows)(q, page_tbl, kv_lens, *pools)
 
 
 def dense_equiv_tokens(batch: int, max_len: int) -> int:

@@ -135,8 +135,7 @@ def paged_mla_attention(p, x, cfg: ArchConfig, mesh, pool, page_tbl, kv_lens,
     [q_absorbed | q_rope] against the full row and values are the leading
     r_kv columns, so each page is read from HBM exactly once
     (share_kv mode of kernels/decode_attention). Returns (y, new_pool)."""
-    from repro.kernels import ops as KOPS
-    from repro.models.kv_pages import write_token
+    from repro.models.kv_pages import decode_attention, write_token
     m = cfg.mla
     positions = kv_lens[:, None]                           # [B, 1]
     scale = (m.qk_nope_dim + m.qk_rope_dim) ** -0.5
@@ -150,9 +149,9 @@ def paged_mla_attention(p, x, cfg: ArchConfig, mesh, pool, page_tbl, kv_lens,
     q_abs = jnp.einsum("bqhk,rhk->bqhr", q_nope, p["wk_b"])  # absorb W_uk
     qcat = jnp.concatenate([q_abs, q_rope], axis=-1)[:, 0]   # [B, H, r+rope]
     eff = kv_lens + active
-    ctx = KOPS.paged_decode_attention(qcat, kvp, None, page_tbl, eff,
-                                      scale=scale, num_kv_splits=num_kv_splits,
-                                      dv=m.kv_lora_rank)     # [B, H, r] f32
+    ctx = decode_attention(mesh, qcat, kvp, None, page_tbl, eff,
+                           scale=scale, num_kv_splits=num_kv_splits,
+                           dv=m.kv_lora_rank)                # [B, H, r] f32
     o = jnp.einsum("bhr,rhk->bhk", ctx.astype(x.dtype), p["wv_b"])  # absorb W_uv
     y = jnp.einsum("bqhk,hkd->bqd", o[:, None], p["wo"])
     return y, {"kv": kvp}

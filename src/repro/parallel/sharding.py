@@ -116,24 +116,30 @@ def abstract_from_specs(specs, mesh: Mesh | None = None,
 
 def init_from_specs(key: jax.Array, specs, mesh: Mesh | None = None,
                     rules: ShardingRules = DEFAULT_RULES):
-    """Materialize parameters (tests/examples; production uses checkpoint)."""
+    """Materialize parameters (tests/examples; production uses checkpoint).
+
+    One jitted program builds every leaf; with a mesh its ``out_shardings``
+    place each leaf under its rules, so every device generates only its own
+    shard and no whole leaf (nor its f32 draw) ever lands on one device."""
     leaves, treedef = jax.tree.flatten(specs, is_leaf=lambda x: isinstance(x, ParamSpec))
-    keys = jax.random.split(key, len(leaves))
 
     def one(k, s: ParamSpec):
         if s.init == "zeros":
-            v = jnp.zeros(s.shape, s.dtype)
-        elif s.init == "ones":
-            v = jnp.ones(s.shape, s.dtype)
-        else:
-            fan_in = s.shape[-2] if len(s.shape) >= 2 else s.shape[-1]
-            std = s.scale / np.sqrt(max(fan_in, 1))
-            v = (jax.random.normal(k, s.shape, jnp.float32) * std).astype(s.dtype)
-        if mesh is not None:
-            v = jax.device_put(v, spec_to_named_sharding(s, mesh, rules))
-        return v
+            return jnp.zeros(s.shape, s.dtype)
+        if s.init == "ones":
+            return jnp.ones(s.shape, s.dtype)
+        fan_in = s.shape[-2] if len(s.shape) >= 2 else s.shape[-1]
+        std = s.scale / np.sqrt(max(fan_in, 1))
+        return (jax.random.normal(k, s.shape, jnp.float32) * std).astype(s.dtype)
 
-    return jax.tree.unflatten(treedef, [one(k, s) for k, s in zip(keys, leaves)])
+    def build(key):
+        keys = jax.random.split(key, len(leaves))
+        return [one(k, s) for k, s in zip(keys, leaves)]
+
+    shardings = (None if mesh is None else
+                 [spec_to_named_sharding(s, mesh, rules) for s in leaves])
+    return jax.tree.unflatten(treedef,
+                              jax.jit(build, out_shardings=shardings)(key))
 
 
 def arch_rules(cfg) -> ShardingRules:

@@ -72,7 +72,7 @@ from repro.checkpoint import (adopt_expert_params, latest_step,
 from repro.core import placement as PL
 from repro.models import get_model
 from repro.models.config import ArchConfig
-from repro.parallel.sharding import init_from_specs
+from repro.parallel.sharding import arch_rules, init_from_specs
 from repro.runtime.fault import (DegradedRecovery, FaultDetector,
                                  PreemptionGuard, StragglerWatchdog)
 from repro.runtime.steps import (make_paged_serve_step, make_serve_step,
@@ -269,10 +269,13 @@ class DecodeServer:
             # per-slot init under a redundant placement would give replicas
             # of one expert different weights, breaking the replica
             # invariant. Physical mode then adopts the initial placement
-            # once (logical -> physical expansion, host-level).
+            # once (logical -> physical expansion, host-level). Expert
+            # weights shard over the EP axis (arch_rules), each shard built
+            # on its own device.
             init_cfg = self._logical_cfg()
             params = init_from_specs(jax.random.PRNGKey(seed),
-                                     self.model.params_spec(init_cfg), mesh)
+                                     self.model.params_spec(init_cfg), mesh,
+                                     arch_rules(init_cfg))
             if self.params_physical and cfg.moe.placement is not None:
                 params = adopt_expert_params(
                     params, self.model.params_spec(init_cfg),
@@ -575,7 +578,8 @@ class DecodeServer:
                             self.params, _ = restore_checkpoint(
                                 self.ckpt_dir, ck,
                                 self.model.params_spec(new_cfg),
-                                mesh=self.mesh, placement=pl)
+                                mesh=self.mesh, rules=arch_rules(new_cfg),
+                                placement=pl)
                         phases["restore_s"] = time.perf_counter() - tp
                         event["restored_from"] = ck
                         self._ckpt_restores += 1
