@@ -1,0 +1,7 @@
+"""Device time in the EP API's ``ep.combine_send`` scope per round trip (us),
+averaged over the chips."""
+from spans import scope_per
+
+
+def read(ctx):
+    return scope_per(ctx, "ep.combine_send", "round_trips", 1e6)

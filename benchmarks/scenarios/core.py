@@ -2,9 +2,9 @@
 engines, telemetry on, acceptance asserted in-bench:
 
   poisson   — Poisson arrivals through the continuous-batching engine; all
-              requests must complete, paged <= dense page accounting, and
-              the emitted Chrome trace must validate with one admit/complete
-              instant per request.
+              requests must complete, paged <= dense page accounting, one
+              admit/complete instant per request, and the profiler trace
+              (Perfetto-readable) holds the serve.* step spans.
   bursty    — synchronized arrival bursts larger than the slot count; a
               queue backlog must FORM (visible in the per-step time series)
               and fully drain.
@@ -25,7 +25,7 @@ engines, telemetry on, acceptance asserted in-bench:
               must stay bitwise identical across concurrency levels.
 
 Rows land in results/benchmarks/scenarios.json (folded into
-BENCH_ll_kernels.json schema v7); trace/series artifacts under
+BENCH_ll_kernels.json schema v7); profiler-trace/series artifacts under
 results/benchmarks/scenarios/.
 """
 from __future__ import annotations
@@ -45,7 +45,7 @@ from repro.models.kv_pages import (PageAllocator, PagePoolExhausted,
                                    pages_for_tokens)
 from repro.runtime.scheduler import Request
 from repro.runtime.server import ContinuousDecodeServer, DecodeServer
-from repro.runtime.telemetry import Tracer, TimeSeries, validate_chrome_trace
+from repro.runtime.telemetry import Tracer, TimeSeries
 
 ARTIFACTS = RESULTS / "scenarios"
 
@@ -79,21 +79,23 @@ def scenario_poisson(n_req=12, rate=0.5, max_new=8):
     tr, ts = Tracer(), TimeSeries()
     srv = ContinuousDecodeServer(_ll_cfg(), batch=8, max_len=32, mesh=_mesh8(),
                                  page_size=4, tracer=tr, series=ts)
-    m = srv.serve_requests(_requests(arrivals, plens, max_new))
+    trace_dir = ARTIFACTS / "poisson_trace"
+    with jax.profiler.trace(str(trace_dir), create_perfetto_trace=True):
+        m = srv.serve_requests(_requests(arrivals, plens, max_new))
     srv.close()
 
     # ---- acceptance ----
     assert m.requests_completed == n_req, m.requests_completed
     assert m.pages_peak <= m.pages_dense_equiv, (m.pages_peak,
                                                  m.pages_dense_equiv)
-    events = validate_chrome_trace(tr.to_chrome_trace())
-    names = [e["name"] for e in events]
+    events = tr.events()
+    names = [e[1] for e in events]
     assert names.count("admit") == n_req, names.count("admit")
     assert names.count("complete") == n_req, names.count("complete")
-    assert "serve_step" in names and "admission" in names
+    assert "serve.step" in names and "serve.admit" in names
+    trace_path = sorted(trace_dir.glob("**/*.xplane.pb"))[-1]
 
     ARTIFACTS.mkdir(parents=True, exist_ok=True)
-    trace_path = tr.write_chrome_trace(ARTIFACTS / "poisson_trace.json")
     series_path = ts.to_jsonl(ARTIFACTS / "poisson_series.jsonl")
     ttfts = [r["ttft_s"] for r in m.per_request]
     row = dict(scenario="poisson", n_req=n_req, rate_per_step=rate,
@@ -185,8 +187,7 @@ def scenario_drift(window=8, segments=4, drop_factor=0.8, spike_factor=1.25):
     assert imb[2] > imb[1] * spike_factor, (imb, "drift did not spike")
     # window 3 ran under the re-adapted table (heat decay forgetting {0,1})
     assert imb[3] < imb[2] * drop_factor, (imb, "no re-drop after drift")
-    events = validate_chrome_trace(tr.to_chrome_trace())
-    swaps = sum(1 for e in events if e["name"] == "placement_swap")
+    swaps = sum(1 for e in tr.events() if e[1] == "placement_swap")
     assert swaps >= 2, swaps            # adapt + re-adapt at minimum
 
     ARTIFACTS.mkdir(parents=True, exist_ok=True)

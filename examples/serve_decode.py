@@ -11,6 +11,7 @@ import os
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
 import dataclasses
+import pathlib
 import time
 
 import jax
@@ -37,9 +38,8 @@ def run(mode: str, layout: str = "nccl_ep", adopt_once: bool = False,
                                   params_physical=True)
         kw = dict(rebalance_every=16, num_redundant_experts=8)
     if trace:
-        # telemetry (docs/DESIGN.md §11): spans at the existing host-side
-        # step boundaries, exported as Chrome-trace JSON — open the printed
-        # file in Perfetto (ui.perfetto.dev) or chrome://tracing
+        # telemetry (docs/DESIGN.md §11): serve.* spans at the existing
+        # host-side step boundaries, in memory and on the profiler's clock
         from repro.runtime.telemetry import TimeSeries, Tracer
         kw.update(tracer=Tracer(), series=TimeSeries())
     cfg = dataclasses.replace(cfg, moe=moe)
@@ -49,18 +49,25 @@ def run(mode: str, layout: str = "nccl_ep", adopt_once: bool = False,
                        **kw)
     prompts = jnp.asarray(np.random.RandomState(0).randint(
         0, cfg.vocab, (BATCH, PROMPT)), jnp.int32)
-    m = srv.serve(prompts, gen_steps=GEN)
+    if trace:
+        # one profiler trace holds the serve.* spans (host plane) and the
+        # device ops on one clock; open the .json.gz in ui.perfetto.dev
+        out = pathlib.Path("results") / "serve_decode_trace"
+        with jax.profiler.trace(str(out), create_perfetto_trace=True):
+            m = srv.serve(prompts, gen_steps=GEN)
+    else:
+        m = srv.serve(prompts, gen_steps=GEN)
     tag = f"{mode}/{layout}" + ("/adopt-once" if adopt_once else "")
     extra = (f" swaps={len(srv.placements)}" if adopt_once else "")
     print(f"  backend={tag:22s} out_tok/s={m.output_tok_s:8.1f} "
           f"ttft={m.ttft_s*1e3:6.1f}ms itl={m.itl_mean_s*1e3:5.2f}ms "
           f"p99={m.itl_p99_s*1e3:5.2f}ms{extra}")
     if trace:
-        import pathlib
-        out = pathlib.Path("results") / "serve_decode_trace.json"
-        srv.tracer.write_chrome_trace(out)
-        spans = sum(r["count"] for r in m.timeline.values())
-        print(f"  wrote {out} ({spans} events; open in ui.perfetto.dev)")
+        spans = sum(r["count"] for r in m.timeline.values()
+                    if r["ph"] == "X")
+        perfetto = sorted(out.glob("**/perfetto_trace.json.gz"))
+        print(f"  wrote {perfetto[-1]} ({spans} serve.* spans; open in "
+              "ui.perfetto.dev)")
     return m
 
 
@@ -70,5 +77,5 @@ if __name__ == "__main__":
     run("ll", "nccl_ep")     # the paper's optimized LL layout
     run("ll", "deepep")      # the DeepEP layout it improves on
     run("baseline")          # Megatron-style AllToAll dispatcher
-    # EPLB adopt-once rebalancing, telemetry on -> Perfetto-readable trace
+    # EPLB adopt-once rebalancing, telemetry on -> a profiler trace
     run("ll", "nccl_ep", adopt_once=True, trace=True)

@@ -65,9 +65,11 @@ def ep_create_handle(group: EpGroup, topk_idx: jax.Array,
 
     HT/baseline run their metadata exchange here (paper §III-C2); LL's
     exchange is folded in too (strictly earlier than the paper's in-dispatch
-    headers, see docs/DESIGN.md §2)."""
-    return get_backend(group.mode).create_handle(group, topk_idx,
-                                                 topk_weights, num_tokens)
+    headers, see docs/DESIGN.md §2). Runs in the named scope
+    ``ep.handle``."""
+    with jax.named_scope("ep.handle"):
+        return get_backend(group.mode).create_handle(group, topk_idx,
+                                                     topk_weights, num_tokens)
 
 
 def ep_handle_refresh(group: EpGroup, handle: EpHandle,

@@ -105,17 +105,24 @@ class BaseBackend:
         raise NotImplementedError
 
     # -- derived eager + staged surface ------------------------------------
+    # Each phase half runs in a named scope (``ep.dispatch_send``,
+    # ``ep.dispatch_recv``, ``ep.combine_send``, ``ep.combine_recv``): the
+    # same names for every mode, in the compiled program's op metadata.
     def dispatch(self, group, handle, tokens, *, send_only: bool = False):
-        pending = self.dispatch_send(group, handle, tokens)
+        with jax.named_scope("ep.dispatch_send"):
+            pending = self.dispatch_send(group, handle, tokens)
         if send_only:
             return pending
-        return self.dispatch_complete(group, handle, pending)
+        with jax.named_scope("ep.dispatch_recv"):
+            return self.dispatch_complete(group, handle, pending)
 
     def combine(self, group, handle, expert_out, *, send_only: bool = False):
-        pending = self.combine_send(group, handle, expert_out)
+        with jax.named_scope("ep.combine_send"):
+            pending = self.combine_send(group, handle, expert_out)
         if send_only:
             return pending
-        return self.combine_complete(group, handle, pending)
+        with jax.named_scope("ep.combine_recv"):
+            return self.combine_complete(group, handle, pending)
 
     def complete(self, group, handle, pending: EpPending):
         if not isinstance(pending, EpPending):
@@ -126,9 +133,11 @@ class BaseBackend:
                 f"resolved mode {self.mode!r} — handles and pendings are not "
                 "transferable across modes")
         if pending.op == "dispatch":
-            return self.dispatch_complete(group, handle, pending)
+            with jax.named_scope("ep.dispatch_recv"):
+                return self.dispatch_complete(group, handle, pending)
         if pending.op == "combine":
-            return self.combine_complete(group, handle, pending)
+            with jax.named_scope("ep.combine_recv"):
+                return self.combine_complete(group, handle, pending)
         raise ValueError(f"unknown pending op: {pending.op!r}")
 
 

@@ -177,9 +177,10 @@ def paged_attention(p, x, cfg: ArchConfig, mesh, pool, page_tbl, kv_lens,
     kp = write_token(pool["k"], k[:, 0], page_tbl, kv_lens)
     vp = write_token(pool["v"], v[:, 0], page_tbl, kv_lens)
     eff = kv_lens + active            # just-written token counts iff active
-    out = decode_attention(mesh, q[:, 0], kp, vp, page_tbl, eff,
-                           scale=a.head_dim ** -0.5,
-                           num_kv_splits=num_kv_splits)
+    with jax.named_scope("paged_decode"):
+        out = decode_attention(mesh, q[:, 0], kp, vp, page_tbl, eff,
+                               scale=a.head_dim ** -0.5,
+                               num_kv_splits=num_kv_splits)
     out = out.astype(x.dtype)[:, None]                     # [B, 1, Hq, hd]
     y = jnp.einsum("bshk,hkd->bsd", out, p["wo"])
     return y, {"k": kp, "v": vp}

@@ -55,62 +55,63 @@ def layer_spec(cfg: ArchConfig, *, moe_layer: bool):
     return sp
 
 
+def _ffn_half(p, x, cfg: ArchConfig, mesh, new_cache, with_heat):
+    """The FFN half of a layer: ln2, then the MoE block (named scope
+    ``moe``) or the dense FFN, plus the residual. -> (x, new_cache, aux)."""
+    if "moe" in p:
+        with jax.named_scope("moe"):
+            h = rmsnorm(x, p["ln2"], cfg.norm_eps)
+            if with_heat:
+                f, aux, heat = MOE.moe_block(p["moe"], h, cfg, mesh,
+                                             with_heat=True)
+                return x + f, new_cache, (aux, heat)
+            f, aux = MOE.moe_block(p["moe"], h, cfg, mesh)
+            return x + f, new_cache, aux
+    h = rmsnorm(x, p["ln2"], cfg.norm_eps)
+    f, aux = ffn_apply(p["ffn"], h, cfg.act), jnp.float32(0)
+    if with_heat:
+        E = cfg.moe.num_experts if cfg.moe else 1
+        return x + f, new_cache, (aux, jnp.zeros((E,), jnp.float32))
+    return x + f, new_cache, aux
+
+
 def layer_apply(p, x, cfg: ArchConfig, mesh, *, cache=None, window="cfg",
                 positions=None, with_heat=False):
     """-> (x, new_cache, aux). With ``with_heat=True`` aux is the pair
     (aux_loss, expert_heat [E]) — the per-logical-expert routed-token
-    histogram the EPLB serving hook accumulates (runtime/server.py)."""
-    h = rmsnorm(x, p["ln1"], cfg.norm_eps)
-    if cfg.attn and cfg.attn.kind == "mla":
-        a, new_cache = MLA.mla_attention(p["attn"], h, cfg, mesh,
-                                         cache=cache, positions=positions)
-    else:
-        a, new_cache = ATT.attention(p["attn"], h, cfg, mesh, cache=cache,
-                                     window=window, positions=positions)
-    x = x + a
-    h = rmsnorm(x, p["ln2"], cfg.norm_eps)
-    if "moe" in p:
-        if with_heat:
-            f, aux, heat = MOE.moe_block(p["moe"], h, cfg, mesh,
-                                         with_heat=True)
-            return x + f, new_cache, (aux, heat)
-        f, aux = MOE.moe_block(p["moe"], h, cfg, mesh)
-    else:
-        f, aux = ffn_apply(p["ffn"], h, cfg.act), jnp.float32(0)
-        if with_heat:
-            E = cfg.moe.num_experts if cfg.moe else 1
-            return x + f, new_cache, (aux, jnp.zeros((E,), jnp.float32))
-    return x + f, new_cache, aux
+    histogram the EPLB serving hook accumulates (runtime/server.py).
+    Named scopes ``attn`` and ``moe`` mark the two halves in the compiled
+    program's op metadata (the profiler trace's per-layer times)."""
+    with jax.named_scope("attn"):
+        h = rmsnorm(x, p["ln1"], cfg.norm_eps)
+        if cfg.attn and cfg.attn.kind == "mla":
+            a, new_cache = MLA.mla_attention(p["attn"], h, cfg, mesh,
+                                             cache=cache, positions=positions)
+        else:
+            a, new_cache = ATT.attention(p["attn"], h, cfg, mesh, cache=cache,
+                                         window=window, positions=positions)
+        x = x + a
+    return _ffn_half(p, x, cfg, mesh, new_cache, with_heat)
 
 
 def paged_layer_apply(p, x, cfg: ArchConfig, mesh, pool, page_tbl, kv_lens,
                       active, *, num_kv_splits: int, with_heat=False):
     """layer_apply's paged-decode twin: attention runs against the paged KV
     pool (kernels/decode_attention via ops); the FFN/MoE half is identical.
-    -> (x, new_pool, aux) with the same aux contract as layer_apply."""
-    h = rmsnorm(x, p["ln1"], cfg.norm_eps)
-    if cfg.attn and cfg.attn.kind == "mla":
-        a, new_pool = MLA.paged_mla_attention(
-            p["attn"], h, cfg, mesh, pool, page_tbl, kv_lens, active,
-            num_kv_splits=num_kv_splits)
-    else:
-        a, new_pool = ATT.paged_attention(
-            p["attn"], h, cfg, mesh, pool, page_tbl, kv_lens, active,
-            num_kv_splits=num_kv_splits)
-    x = x + a
-    h = rmsnorm(x, p["ln2"], cfg.norm_eps)
-    if "moe" in p:
-        if with_heat:
-            f, aux, heat = MOE.moe_block(p["moe"], h, cfg, mesh,
-                                         with_heat=True)
-            return x + f, new_pool, (aux, heat)
-        f, aux = MOE.moe_block(p["moe"], h, cfg, mesh)
-    else:
-        f, aux = ffn_apply(p["ffn"], h, cfg.act), jnp.float32(0)
-        if with_heat:
-            E = cfg.moe.num_experts if cfg.moe else 1
-            return x + f, new_pool, (aux, jnp.zeros((E,), jnp.float32))
-    return x + f, new_pool, aux
+    -> (x, new_pool, aux) with the same aux contract and scopes as
+    layer_apply."""
+    with jax.named_scope("attn"):
+        h = rmsnorm(x, p["ln1"], cfg.norm_eps)
+        if cfg.attn and cfg.attn.kind == "mla":
+            a, new_pool = MLA.paged_mla_attention(
+                p["attn"], h, cfg, mesh, pool, page_tbl, kv_lens, active,
+                num_kv_splits=num_kv_splits)
+        else:
+            a, new_pool = ATT.paged_attention(
+                p["attn"], h, cfg, mesh, pool, page_tbl, kv_lens, active,
+                num_kv_splits=num_kv_splits)
+        x = x + a
+    return _ffn_half(p, x, cfg, mesh, new_pool, with_heat)
 
 
 def _stack(specs, n: int):
@@ -221,6 +222,14 @@ def lm_forward(params, batch, cfg: ArchConfig, mesh):
     return loss + aux, dict(aux=aux)
 
 
+def _lm_head(params, x, cfg: ArchConfig):
+    """Final norm and LM head of a decode step (named scope ``head``)."""
+    with jax.named_scope("head"):
+        x = rmsnorm(x, params["ln_f"], cfg.norm_eps)
+        head = params["embed"] if cfg.tie_embeddings else params["lm_head"]
+        return logits_out(x, head)
+
+
 def lm_decode_state_spec(cfg: ArchConfig, batch: int, max_len: int, *, long=False):
     n_dense = cfg.moe.first_k_dense if cfg.moe else cfg.num_layers
     n_moe = cfg.num_layers - n_dense if cfg.moe else 0
@@ -267,9 +276,7 @@ def lm_decode_step(params, state, batch, cfg: ArchConfig, mesh):
         else:
             x, new_state["moe"], _ = _scan_stack(
                 body, x, params["moe_stack"], state["moe"], cfg, remat=False)
-    x = rmsnorm(x, params["ln_f"], cfg.norm_eps)
-    head = params["embed"] if cfg.tie_embeddings else params["lm_head"]
-    return logits_out(x, head), new_state
+    return _lm_head(params, x, cfg), new_state
 
 
 def lm_paged_decode_state_spec(cfg: ArchConfig, num_pages: int,
@@ -340,9 +347,7 @@ def lm_paged_decode_step(params, state, batch, cfg: ArchConfig, mesh):
         else:
             x, new_state["moe"], _ = _scan_stack(
                 body, x, params["moe_stack"], state["moe"], cfg, remat=False)
-    x = rmsnorm(x, params["ln_f"], cfg.norm_eps)
-    head = params["embed"] if cfg.tie_embeddings else params["lm_head"]
-    return logits_out(x, head), new_state
+    return _lm_head(params, x, cfg), new_state
 
 
 # --------------------------------------------------------------------------

@@ -104,6 +104,12 @@ class ContinuousScheduler:
         self._lens = np.zeros((self.B,), np.int32)
         self._active = np.zeros((self.B,), np.int32)
         self._tokens = np.zeros((self.B, 1), np.int32)
+        # the last advance()'s counters (the server puts them on its
+        # serve.admit span): live rows, KV tokens the step's paged decode
+        # reads (sum of kv_len + 1), rows still feeding their prompt, and
+        # the requests admitted with their summed queue wait in steps
+        self.counters = dict(active=0, kv_tokens=0, prefill_rows=0,
+                             admitted=0, queued_steps=0)
 
     # ---- queries ----
 
@@ -126,8 +132,10 @@ class ContinuousScheduler:
         """Admit arrivals into free slots (FIFO, reservation-gated), alloc
         page-boundary pages for every live request, and build this step's
         batch inputs. Returns dict(tokens, page_tbl, kv_lens, active) of
-        fixed-shape int32 numpy arrays."""
+        fixed-shape int32 numpy arrays, and leaves this boundary's
+        ``counters``."""
         now = time.perf_counter() if now is None else now
+        admitted = queued_steps = 0
         # admission: strictly FIFO — a too-big head-of-line request blocks
         # later ones (no reordering; keeps arrival order deterministic)
         for i in range(self.B):
@@ -144,9 +152,12 @@ class ContinuousScheduler:
             self._reserved += need
             self._tbl[i, :] = self.alloc.pad_page
             self._lens[i] = 0
+            admitted += 1
+            queued_steps += step - r.arrival_step
             if self.tracer is not None:
                 self.tracer.instant("admit", rid=r.rid, step=step, slot=i,
                                     queued=len(self.queue))
+        active = kv_tokens = prefill_rows = 0
         for i, s in enumerate(self.slots):
             if s is None:
                 self._active[i] = 0
@@ -164,6 +175,12 @@ class ContinuousScheduler:
                                   else s.generated[pos - L])
             self._lens[i] = pos
             self._active[i] = 1
+            active += 1
+            kv_tokens += pos + 1
+            prefill_rows += pos < L
+        self.counters = dict(active=active, kv_tokens=kv_tokens,
+                             prefill_rows=prefill_rows, admitted=admitted,
+                             queued_steps=queued_steps)
         return dict(tokens=self._tokens.copy(),
                     page_tbl=self._tbl.copy(),
                     kv_lens=self._lens.copy(),

@@ -411,7 +411,7 @@ class DecodeServer:
         dev = self._device_heat()
         if dev is None:
             return
-        with self.tracer.span("rebalance", step=step_idx):
+        with self.tracer.span("serve.rebalance", step=step_idx):
             self._sched.observe(dev)
             self._heat_drained = (dev if self._heat_drained is None
                                   else self._heat_drained + dev)
@@ -441,7 +441,7 @@ class DecodeServer:
                 # exactly once per adoption (old buffers donated — peak
                 # memory ~one set of expert weights). The re-jitted step
                 # then runs with zero per-step expansion cost.
-                with self.tracer.span("adopt", step=step_idx):
+                with self.tracer.span("serve.adopt", step=step_idx):
                     self.params = adopt_expert_params(
                         self.params,
                         self.model.params_spec(self._logical_cfg()),
@@ -468,24 +468,37 @@ class DecodeServer:
         adoption, not one per dead rank."""
         if self._detector is None:
             return None
-        with self.tracer.span("fault_poll"):
-            if self._injector is not None:
-                self._injector.advance(step_idx)
-                for r in range(self._detector.num_ranks):
-                    if self._injector.is_alive(r):
-                        self._detector.heartbeat(r, step_idx)
-            merged = self._detector.poll(step_idx)
-            while merged:
-                more = self._detector.poll(step_idx)
-                if not more:
-                    break
-                merged = merged.merge(more)
+        if self._injector is not None:
+            self._injector.advance(step_idx)
+            for r in range(self._detector.num_ranks):
+                if self._injector.is_alive(r):
+                    self._detector.heartbeat(r, step_idx)
+        merged = self._detector.poll(step_idx)
+        while merged:
+            more = self._detector.poll(step_idx)
+            if not more:
+                break
+            merged = merged.merge(more)
         if not merged:
             return None
         self.tracer.instant("fault_detected", step=step_idx,
                             died=list(merged.died),
                             rejoined=list(merged.rejoined))
         return merged
+
+    def _poll_boundary(self, step_idx: int):
+        """The fault poll and the rebalance check of one unpipelined step
+        boundary (the ``serve.poll`` span): recover on a fault report,
+        else take the periodic rebalance."""
+        with self.tracer.span("serve.poll"):
+            report = self._poll_faults(step_idx)
+            if report is not None:
+                # recovery drains the heat window and advances the
+                # placement itself — a coinciding periodic boundary would
+                # just dedup to the same table
+                self._recover(step_idx, report)
+            else:
+                self._maybe_rebalance(step_idx)
 
     def _recover(self, step_idx: int, report):
         """One shrink or expand transition (docs/DESIGN.md §9). Drains the
@@ -507,7 +520,7 @@ class DecodeServer:
         # placement build; adopt = masked weight rebind; restore = the
         # checkpoint fallback. Each also lands as a nested tracer span.
         phases: dict[str, float] = {}
-        with self.tracer.span(f"recover:{kind}", step=step_idx,
+        with self.tracer.span(f"serve.recover:{kind}", step=step_idx,
                               died=list(report.died),
                               rejoined=list(report.rejoined)):
             dev = self._device_heat()
@@ -523,7 +536,7 @@ class DecodeServer:
                 self.state["expert_heat"] = jnp.zeros_like(
                     self.state["expert_heat"])
             tp = time.perf_counter()
-            with self.tracer.span("recover:repack"):
+            with self.tracer.span("serve.recover:repack"):
                 self._sched.set_alive(self._detector.alive)
                 old = self.cfg.moe.placement
                 pl = self._sched.advance()
@@ -573,7 +586,7 @@ class DecodeServer:
                             self.cfg, moe=dataclasses.replace(self.cfg.moe,
                                                               placement=pl))
                         tp = time.perf_counter()
-                        with self.tracer.span("checkpoint", restore=True,
+                        with self.tracer.span("serve.checkpoint", restore=True,
                                               ckpt_step=ck):
                             self.params, _ = restore_checkpoint(
                                 self.ckpt_dir, ck,
@@ -587,7 +600,7 @@ class DecodeServer:
                         src = (PL.mask_placement(src_live, self._sched.alive)
                                if report.died else old)
                         tp = time.perf_counter()
-                        with self.tracer.span("recover:adopt"):
+                        with self.tracer.span("serve.recover:adopt"):
                             self.params = adopt_expert_params(
                                 self.params,
                                 self.model.params_spec(self._logical_cfg()),
@@ -615,7 +628,8 @@ class DecodeServer:
         if self.ckpt_dir is None:
             return
         pl = self.cfg.moe.placement if self.cfg.moe else None
-        with self.tracer.span("checkpoint", step=step_idx, preempt=True):
+        with self.tracer.span("serve.checkpoint", step=step_idx,
+                              preempt=True):
             save_checkpoint(
                 self.ckpt_dir, step_idx + 1, self.params,
                 placement=pl if self.params_physical else None,
@@ -635,7 +649,7 @@ class DecodeServer:
         family-agnostic; a production server runs a fused prefill)."""
         t0 = time.perf_counter()
         tok = None
-        with self.tracer.span("prefill", tokens=int(prompts.shape[1])):
+        with self.tracer.span("serve.prefill", tokens=int(prompts.shape[1])):
             for i in range(prompts.shape[1]):
                 tok, self.state = self.step(self.params, self.state,
                                             {"tokens": prompts[:, i:i + 1]})
@@ -651,22 +665,16 @@ class DecodeServer:
         record_itls = self.series.enabled
         for i in range(steps):
             t0 = time.perf_counter()
-            with self.tracer.span("serve_step"):
+            with self.tracer.span("serve.step"):
                 tok, self.state = self.step(self.params, self.state,
                                             {"tokens": tok})
                 jax.block_until_ready(tok)
             itls.append(time.perf_counter() - t0)
             if record_itls:
                 self._win_itls.append(itls[-1])
-            outs.append(np.asarray(tok))
-            report = self._poll_faults(i)
-            if report is not None:
-                # recovery drains the heat window and advances the
-                # placement itself — a coinciding periodic boundary would
-                # just dedup to the same table
-                self._recover(i, report)
-            else:
-                self._maybe_rebalance(i)
+            with self.tracer.span("serve.readback"):
+                outs.append(np.asarray(tok))
+            self._poll_boundary(i)
             if self._detector is not None and self._detector.dead:
                 self._degraded_steps += 1
             if self.guard.should_stop:
@@ -698,7 +706,8 @@ class DecodeServer:
                 done.append(d)
             boundary = (self._sched is not None and self.rebalance_every
                         and (i + 1) % self.rebalance_every == 0)
-            report = self._poll_faults(i)
+            with self.tracer.span("serve.poll"):
+                report = self._poll_faults(i)
             if boundary or report is not None or self.guard.should_stop:
                 # placement swap / recovery / preemption boundary: drain the
                 # in-flight window first (a swap re-jits the step; in-flight
@@ -706,7 +715,7 @@ class DecodeServer:
                 # The drain and any post-swap recompile are charged to the
                 # ITL stream on purpose — swaps and recoveries cost real
                 # latency, and the serving metrics should show it.
-                with self.tracer.span("drain", pending=len(pending)):
+                with self.tracer.span("serve.drain", pending=len(pending)):
                     while pending:
                         d = pending.popleft()
                         jax.block_until_ready(d)
@@ -879,13 +888,17 @@ class ContinuousDecodeServer(DecodeServer):
         while not sched.done:
             if max_steps is not None and step_idx >= max_steps:
                 break
-            with self.tracer.span("admission"):
+            # one step = four boundary spans; the admission counters ride
+            # on serve.admit (host state the scheduler already holds)
+            with self.tracer.span("serve.admit") as span:
                 feed = sched.advance(step_idx)
-            with self.tracer.span("serve_step"):
+                span.set_metadata(**sched.counters)
+            with self.tracer.span("serve.step"):
                 tok, self.state = self.step(self.params, self.state, feed)
                 jax.block_until_ready(tok)
             now = time.perf_counter()
-            sched.observe(np.asarray(tok), now)
+            with self.tracer.span("serve.readback"):
+                sched.observe(np.asarray(tok), now)
             if record:
                 # pure host state — engine occupancy at this boundary
                 itl = now - (marks[-1] if marks else t0)
@@ -896,11 +909,7 @@ class ContinuousDecodeServer(DecodeServer):
                     pages_live=allocator.live_count,
                     pages_peak=allocator.peak_live)
             marks.append(now)
-            report = self._poll_faults(step_idx)
-            if report is not None:
-                self._recover(step_idx, report)
-            else:
-                self._maybe_rebalance(step_idx)
+            self._poll_boundary(step_idx)
             if self._detector is not None and self._detector.dead:
                 self._degraded_steps += 1
             if self.guard.should_stop:

@@ -103,13 +103,20 @@ def make_train_step(cfg: ArchConfig, mesh, opt_cfg: AdamWConfig | None = None):
     return train_step
 
 
+def _greedy(logits, cfg: ArchConfig):
+    """Greedy next token [B, 1] from the last position's logits, in the
+    ``head`` named scope the model's final norm and LM head open."""
+    with jax.named_scope("head"):
+        nxt = jnp.argmax(logits[:, -1, :cfg.vocab], axis=-1).astype(jnp.int32)
+        return nxt[:, None]
+
+
 def make_serve_step(cfg: ArchConfig, mesh):
     model = get_model(cfg)
 
     def serve_step(params, state, batch):
         logits, state = model.decode_step(params, state, batch, cfg, mesh)
-        next_tok = jnp.argmax(logits[:, -1, :cfg.vocab], axis=-1).astype(jnp.int32)
-        return next_tok[:, None], state
+        return _greedy(logits, cfg), state
 
     return serve_step
 
@@ -126,7 +133,6 @@ def make_paged_serve_step(cfg: ArchConfig, mesh):
 
     def serve_step(params, state, batch):
         logits, state = model.paged_decode_step(params, state, batch, cfg, mesh)
-        next_tok = jnp.argmax(logits[:, -1, :cfg.vocab], axis=-1).astype(jnp.int32)
-        return next_tok[:, None], state
+        return _greedy(logits, cfg), state
 
     return serve_step

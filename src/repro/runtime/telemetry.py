@@ -1,4 +1,5 @@
-"""Host-side serving telemetry: step-timeline tracer + windowed time series.
+"""Host-side serving telemetry: step-boundary spans on the profiler's clock
++ windowed time series.
 
 The serving loop (PRs 4-8) makes load-bearing runtime decisions — heat-driven
 placement swaps, fault shrink/expand, admission/retirement, page allocation —
@@ -6,9 +7,15 @@ that were previously only visible as end-of-run ``ServeMetrics`` scalars.
 This module makes them observable without perturbing the thing observed:
 
 * ``Tracer`` records named spans and instant events at EXISTING host-side
-  step boundaries (``serve_step``, ``prefill``, ``rebalance``, ``adopt``,
-  ``fault_poll``, ``recover:shrink`` / ``recover:expand``, ``admission``,
-  ``checkpoint``) and exports Chrome-trace / Perfetto JSON.
+  step boundaries (``serve.admit``, ``serve.step``, ``serve.readback``,
+  ``serve.poll``, ``serve.prefill``, ``serve.rebalance``, ``serve.adopt``,
+  ``serve.recover:shrink`` / ``serve.recover:expand``, ``serve.checkpoint``,
+  ``serve.drain``). Every span also enters a
+  ``jax.profiler.TraceAnnotation`` carrying its args, so under a profiler
+  session (``jax.profiler.trace(dir, create_perfetto_trace=True)``) the
+  spans land on the host plane of the same ``.xplane.pb`` / Perfetto trace
+  as the device ops, on one clock. In memory the tracer keeps the events
+  for ``summary()`` (``ServeMetrics.timeline``) and ``events()``.
 * ``TimeSeries`` records per-window rows (ITL, queue depth, active slots,
   pages live/peak, per-rank heat + imbalance ratio, alive ranks,
   straggler/rebase counters) and exports JSONL.
@@ -20,8 +27,11 @@ Hard contracts (pinned by tests/test_telemetry.py):
   rows reuse the ``device_get`` the rebalancer/recovery path already
   performs. Decode token streams are bitwise identical tracing on vs off.
 * **Disabled == no-op.** ``NULL_TRACER`` / ``NULL_SERIES`` are shared
-  singletons whose methods allocate nothing per step (``span`` returns one
-  shared no-op context manager; ``record`` returns immediately).
+  singletons whose methods allocate nothing per step while no profiler
+  session is active (``span`` returns one shared no-op context manager;
+  ``record`` returns immediately). Under a profiler session ``NULL_TRACER``
+  spans are bare ``TraceAnnotation``s: the serving loop's boundary spans
+  reach the profiler with or without an in-memory tracer.
 * **Deterministic tests.** The clock is injectable (monotonic callable
   returning seconds); tests drive a fake clock and assert exact durations.
 """
@@ -30,7 +40,9 @@ from __future__ import annotations
 import json
 import pathlib
 import time
-from typing import Any, Callable, Iterable
+from typing import Callable
+
+from jax.profiler import TraceAnnotation
 
 
 def json_safe(obj):
@@ -60,47 +72,55 @@ class _NullSpan:
     def __exit__(self, *exc):
         return False
 
+    def set_metadata(self, **args):
+        return None
+
 
 _NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    """Context manager recording one complete ("X") Chrome-trace event."""
-    __slots__ = ("_tracer", "_name", "_args", "_t0")
+    """Context manager recording one span in memory and in the profiler.
+    ``set_metadata`` adds args once the span is open (counters known only
+    at its end), as ``TraceAnnotation.set_metadata`` does."""
+    __slots__ = ("_tracer", "_name", "_args", "_t0", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, args: dict):
         self._tracer = tracer
         self._name = name
         self._args = args
         self._t0 = 0.0
+        self._ann = None
 
     def __enter__(self):
+        self._ann = TraceAnnotation(self._name, **self._args)
+        self._ann.__enter__()
         self._t0 = self._tracer.clock()
         return self
+
+    def set_metadata(self, **args):
+        self._args.update(args)
+        self._ann.set_metadata(**args)
 
     def __exit__(self, *exc):
         tr = self._tracer
         tr._events.append(("X", self._name, self._t0,
                            tr.clock() - self._t0, self._args))
+        self._ann.__exit__(*exc)
         return False
 
 
 class Tracer:
     """Named spans + instant events with an injectable monotonic clock.
 
-    Events are stored as host tuples ``(ph, name, t_s, dur_s, args)`` and
-    exported as Chrome-trace JSON (``ts``/``dur`` in microseconds relative
-    to the tracer's construction time), loadable in Perfetto / chrome://tracing.
-    """
+    Events are stored as host tuples ``(ph, name, t_s, dur_s, args)``:
+    ``"X"`` spans, ``"i"`` instants, ``"C"`` counters. Spans also go to the
+    profiler (module docstring); instants and counters stay in memory."""
 
     enabled = True
 
-    def __init__(self, clock: Callable[[], float] = time.perf_counter,
-                 pid: int = 0, tid: int = 0):
+    def __init__(self, clock: Callable[[], float] = time.perf_counter):
         self.clock = clock
-        self.pid = pid
-        self.tid = tid
-        self._t0 = clock()
         self._events: list[tuple] = []   # (ph, name, t_s, dur_s, args)
 
     # -- recording ---------------------------------------------------------
@@ -114,7 +134,7 @@ class Tracer:
     def counter(self, name: str, value: float) -> None:
         self._events.append(("C", name, self.clock(), 0.0, {"value": value}))
 
-    # -- export ------------------------------------------------------------
+    # -- reading -----------------------------------------------------------
     def __len__(self) -> int:
         return len(self._events)
 
@@ -132,34 +152,19 @@ class Tracer:
                 row["total_s"] = round(row["total_s"] + float(dur), 9)
         return out
 
-    def to_chrome_trace(self) -> dict:
-        ev = []
-        for ph, name, t, dur, args in self._events:
-            e = {"name": name, "ph": ph, "pid": self.pid, "tid": self.tid,
-                 "ts": round((t - self._t0) * 1e6, 3)}
-            if ph == "X":
-                e["dur"] = round(dur * 1e6, 3)
-            if ph == "i":
-                e["s"] = "t"                      # thread-scoped instant
-            if args:
-                e["args"] = json_safe(args)
-            ev.append(e)
-        return {"traceEvents": ev, "displayTimeUnit": "ms"}
-
-    def write_chrome_trace(self, path) -> pathlib.Path:
-        path = pathlib.Path(path)
-        path.parent.mkdir(parents=True, exist_ok=True)
-        path.write_text(json.dumps(self.to_chrome_trace()))
-        return path
-
 
 class NullTracer:
-    """Disabled tracer: every method is a no-op with no per-call allocation
-    (``span`` returns one shared context-manager object)."""
+    """Disabled tracer: nothing in memory. Outside a profiler session every
+    method is a no-op with no per-call allocation (``span`` returns one
+    shared context-manager object); inside one, ``span`` is a bare
+    ``TraceAnnotation``."""
 
     enabled = False
 
     def span(self, name, **args):
+        # TraceMe's own "is a session recording" check, ~0.1 µs
+        if TraceAnnotation.is_enabled():
+            return TraceAnnotation(name, **args)
         return _NULL_SPAN
 
     def instant(self, name, **args):
@@ -176,9 +181,6 @@ class NullTracer:
 
     def summary(self):
         return {}
-
-    def to_chrome_trace(self):
-        return {"traceEvents": [], "displayTimeUnit": "ms"}
 
 
 NULL_TRACER = NullTracer()
@@ -223,59 +225,7 @@ class NullTimeSeries:
 NULL_SERIES = NullTimeSeries()
 
 
-def validate_chrome_trace(obj: dict) -> list[dict]:
-    """Assert ``obj`` is well-formed Chrome-trace JSON; return its events.
-
-    Checks the event-format invariants CI relies on: a ``traceEvents`` list;
-    every event has ``name``/``ph``/``pid``/``tid``/``ts`` with ``ph`` in
-    {X, i, C}; ``ts >= 0`` and ``dur >= 0``; and complete ("X") spans
-    properly NEST per (pid, tid) — a span either contains or is disjoint
-    from every other span on its track (no partial overlap).
-    """
-    assert isinstance(obj, dict), f"trace root must be a dict, got {type(obj)}"
-    events = obj.get("traceEvents")
-    assert isinstance(events, list), "trace must carry a traceEvents list"
-    tracks: dict[tuple, list[tuple]] = {}
-    for i, e in enumerate(events):
-        assert isinstance(e, dict), f"event {i} is not an object: {e!r}"
-        for key in ("name", "ph", "pid", "tid", "ts"):
-            assert key in e, f"event {i} missing {key!r}: {e!r}"
-        assert e["ph"] in ("X", "i", "C"), f"event {i} bad ph: {e['ph']!r}"
-        assert e["ts"] >= 0, f"event {i} negative ts: {e['ts']}"
-        if e["ph"] == "X":
-            assert "dur" in e, f"span event {i} missing dur: {e!r}"
-            assert e["dur"] >= 0, f"event {i} negative dur: {e['dur']}"
-            tracks.setdefault((e["pid"], e["tid"]), []).append(
-                (float(e["ts"]), float(e["ts"]) + float(e["dur"]), e["name"]))
-    eps = 1e-6        # µs rounding slack from the 3-decimal export
-    for track, spans in tracks.items():
-        # sort by start asc, end desc: a containing span sorts before its
-        # children, so a containment stack detects partial overlap.
-        spans.sort(key=lambda s: (s[0], -s[1]))
-        stack: list[tuple] = []
-        for t0, t1, name in spans:
-            while stack and t0 >= stack[-1][1] - eps:
-                stack.pop()
-            if stack:
-                assert t1 <= stack[-1][1] + eps, (
-                    f"track {track}: span {name!r} [{t0}, {t1}] partially "
-                    f"overlaps {stack[-1][2]!r} [{stack[-1][0]}, "
-                    f"{stack[-1][1]}] — spans must nest")
-            stack.append((t0, t1, name))
-    return events
-
-
-def load_chrome_trace(path) -> dict:
-    return json.loads(pathlib.Path(path).read_text())
-
-
-def span_names(events: Iterable[dict]) -> list[str]:
-    """Names of complete ("X") events, in file order."""
-    return [e["name"] for e in events if e.get("ph") == "X"]
-
-
 __all__ = [
     "Tracer", "NullTracer", "NULL_TRACER",
-    "TimeSeries", "NullTimeSeries", "NULL_SERIES",
-    "json_safe", "validate_chrome_trace", "load_chrome_trace", "span_names",
+    "TimeSeries", "NullTimeSeries", "NULL_SERIES", "json_safe",
 ]
