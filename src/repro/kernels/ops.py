@@ -7,8 +7,8 @@ interpret-mode Pallas everywhere (slow; used by kernel tests and debugging).
 
 Every EP hot-path op is fused single-pass on TPU: dispatch_pack (slot gather
 + fp8 quant), recv_unpack (slot gather + fp8 dequant, its recv-side mirror),
-combine_gather_reduce (slot gather + K-way weighted reduce), combine_reduce,
-quantize/dequantize_fp8, grouped_gemm, flash attention.
+combine_gather_reduce (token-blocked slot gather + K-way weighted reduce),
+combine_reduce, quantize/dequantize_fp8, grouped_gemm, flash attention.
 """
 from __future__ import annotations
 

@@ -11,6 +11,7 @@ import pytest
 from repro.kernels import ref
 from repro.kernels.combine_reduce import combine_reduce as cr_pallas
 from repro.kernels.combine_gather_reduce import combine_gather_reduce as cgr_pallas
+from repro.kernels.combine_gather_reduce import token_block as cgr_token_block
 from repro.kernels.dispatch_pack import dispatch_pack as dp_pallas
 from repro.kernels.fp8 import quantize_fp8 as qfp8_pallas
 from repro.kernels.fp8 import dequantize_fp8 as dqfp8_pallas
@@ -98,11 +99,17 @@ def test_grouped_gemm_count_masking():
     np.testing.assert_allclose(got[0, :100], want, rtol=1e-4, atol=1e-4)
 
 
-@pytest.mark.parametrize("R,T,K,H", [(32, 8, 2, 128), (16, 8, 4, 256), (64, 4, 1, 128),
-                                     (16, 4, 2, 640)])
+@pytest.mark.parametrize("R,T,K,H", [
+    (32, 8, 2, 128), (16, 8, 4, 256), (64, 4, 1, 128), (16, 4, 2, 640),
+    (64, 20, 8, 256),      # T not a multiple of the token block (16)
+    (96, 36, 8, 128),      # three blocks, the last partly padding
+    (128, 16, 8, 256),     # K = 8, one full block
+    (64, 12, 16, 4096),    # f32: the double buffer halves the block to 8
+])
 @pytest.mark.parametrize("dt", [jnp.float32, jnp.bfloat16])
 def test_combine_gather_reduce(R, T, K, H, dt):
-    """Fused gather+reduce vs the two-pass oracle, sentinel rows included."""
+    """Fused gather+reduce vs the two-pass oracle, sentinel rows included;
+    token blocks that do not divide T, and T below the block (T = 4)."""
     rng = np.random.RandomState(7)
     recv = jnp.asarray(rng.randn(R, H), dt)
     rows = jnp.asarray(rng.randint(0, R + 1, (T, K)), jnp.int32)  # R == sentinel
@@ -120,6 +127,35 @@ def test_combine_gather_reduce_all_sentinel():
     w = jnp.ones((4, 2), jnp.float32)
     got = np.asarray(cgr_pallas(recv, rows, w, interpret=True))
     assert np.all(got == 0)
+
+
+@pytest.mark.parametrize("bad", [np.inf, np.nan])
+def test_combine_gather_reduce_sentinel_beside_nonfinite(bad):
+    """A sentinel entry gathers the clamped last row; with that row inf or
+    NaN it must still contribute exactly zero."""
+    R, T, K, H = 16, 12, 4, 256
+    rng = np.random.RandomState(10)
+    recv = rng.randn(R, H).astype(np.float32)
+    recv[R - 1] = bad
+    rows = rng.randint(0, R - 1, (T, K))
+    rows[::2, 1] = R                                  # sentinels in every other token
+    rows[3] = R                                       # and one token of nothing but
+    w = jax.nn.softmax(jnp.asarray(rng.randn(T, K), jnp.float32), -1)
+    recv, rows = jnp.asarray(recv), jnp.asarray(rows, jnp.int32)
+    got = np.asarray(cgr_pallas(recv, rows, w, interpret=True))
+    want = np.asarray(ref.combine_gather_reduce(recv, rows, w))
+    assert np.all(np.isfinite(got)) and np.all(got[3] == 0)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("T,K,H,itemsize,tb", [
+    (4096, 8, 7168, 2, 16),     # dsv3-ep-ht
+    (128, 8, 7168, 2, 16),      # dsv3-ep-ll-4chip
+    (4, 8, 7168, 2, 4),         # fewer tokens than a block
+    (4096, 8, 7168, 4, 8),      # f32 rows: 16 would overflow the buffer
+])
+def test_combine_gather_reduce_token_block_size(T, K, H, itemsize, tb):
+    assert cgr_token_block(T, K, H, itemsize) == tb
 
 
 @pytest.mark.parametrize("R,H,D,C", [(32, 128, 2, 8), (16, 256, 4, 4),

@@ -76,9 +76,11 @@ def test_recv_unpack(one_chip, quant):
     _compile(ru.recv_unpack, one_chip, *shapes)
 
 
-def test_combine_gather_reduce(one_chip):
+@pytest.mark.parametrize("R,T", [(32768, 4096), (4096, 128)], ids=["ht", "ll"])
+def test_combine_gather_reduce(one_chip, R, T):
+    # the EP cells' combine: HT 4096 tokens x top-8 on one chip, LL 128
     _compile(cgr.combine_gather_reduce, one_chip,
-             ((1024, H), BF16), ((128, 8), I32), ((128, 8), F32))
+             ((R, H), BF16), ((T, 8), I32), ((T, 8), F32))
 
 
 def test_combine_reduce(one_chip):
