@@ -4,6 +4,10 @@ top-8, sigmoid gating, group-limited (8 groups, top-4), aux-loss-free bias,
 first 3 layers dense, MTP. **The paper's primary workload family** — this is
 the arch the NCCL EP evaluation models (256 experts, hidden 7168, top-8).
 
+Rope: YaRN over the 64 rotary dims (factor 40 over 4096 original positions,
+beta_fast 32, beta_slow 1, mscale = mscale_all_dim = 1), so the softmax
+scale is 192^-0.5 · (0.1·ln 40 + 1)²; RMSNorm eps 1e-6.
+
 EP deployment per shape (mirrors §VI/VII):
   train/prefill: HT mode, wide EP over ("data","model") = 256 ranks, L=1,
                  hierarchical two-stage a2a (outer=data, inner=model);
@@ -12,7 +16,12 @@ EP deployment per shape (mirrors §VI/VII):
 """
 import dataclasses
 
-from repro.models.config import ArchConfig, AttnSpec, MLASpec, MoESpec
+from repro.models.config import (ArchConfig, AttnSpec, MLASpec, MoESpec,
+                                 YarnScaling)
+
+# the published rope_scaling (config.json "rope_scaling", type "yarn")
+YARN = YarnScaling(factor=40.0, original_max_position=4096, beta_fast=32.0,
+                   beta_slow=1.0, mscale=1.0, mscale_all_dim=1.0)
 
 
 def full_config(shape=None):
@@ -46,8 +55,8 @@ def full_config(shape=None):
         d_ff=18432, vocab=129280,
         attn=AttnSpec(n_heads=128, n_kv=128, head_dim=128, kind="mla"),
         mla=MLASpec(q_lora_rank=1536, kv_lora_rank=512, qk_nope_dim=128,
-                    qk_rope_dim=64, v_head_dim=128),
-        moe=moe, mtp=(kind == "train"), microbatch=micro,
+                    qk_rope_dim=64, v_head_dim=128, rope_scaling=YARN),
+        moe=moe, mtp=(kind == "train"), microbatch=micro, norm_eps=1e-6,
     )
 
 
@@ -57,7 +66,7 @@ def smoke_config():
         d_ff=128, vocab=256,
         attn=AttnSpec(n_heads=4, n_kv=4, head_dim=16, kind="mla"),
         mla=MLASpec(q_lora_rank=32, kv_lora_rank=16, qk_nope_dim=16,
-                    qk_rope_dim=8, v_head_dim=16),
+                    qk_rope_dim=8, v_head_dim=16, rope_scaling=YARN),
         moe=MoESpec(num_experts=8, top_k=2, d_ff_expert=32, shared_experts=1,
                     first_k_dense=1, gating="sigmoid", n_groups=2,
                     topk_groups=1, use_selection_bias=True,

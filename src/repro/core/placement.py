@@ -249,6 +249,12 @@ def identity_placement(num_experts: int, num_ranks: int) -> EpPlacement:
         tuple(range(r * L, (r + 1) * L)) for r in range(num_ranks)))
 
 
+def rank_experts(placement: EpPlacement, rank: int) -> tuple[int, ...]:
+    """The logical experts rank ``rank`` hosts, in slot order (empty slots
+    left out): what that chip holds of every MoE layer."""
+    return tuple(e for e in placement.slot_expert[rank] if e != EMPTY)
+
+
 # --------------------------------------------------------------------------
 # derived tables + plan-time assignment
 # --------------------------------------------------------------------------

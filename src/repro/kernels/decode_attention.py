@@ -28,10 +28,16 @@ request, and an empty split/request yields o == 0, lse == NEG_INF exactly.
 Page tables pad unused entries with the pool's zero pad page (index P), so
 the index_map stays branch-free.
 
-Absorbed-MLA decode shares one pool between K and V (``share_kv=True``): the
-page payload is [ckv | k_rope] with Hkv == 1, queries attend over the full
-row, and values are its first ``dv = r_kv`` columns — each page is read from
-HBM exactly once.
+Absorbed-MLA decode shares the key pool with V (``share_kv``): Hkv == 1, the
+key pool holds the latent ``c_kv`` [.., r_kv] and is the value pool too, and
+a second pool holds the shared rotary key ``k_rope`` [.., rope]. The query
+is [q_absorbed | q_rope]; a page's scores are q_absorbed·c_kvᵀ +
+q_rope·k_ropeᵀ over all query heads at once ([Hq, r_kv] × [r_kv, page]), and
+its values are c_kv itself — each page's latent row is read from HBM once,
+and the 64-wide rope block is legal as its array's full last dimension, so
+no pad bytes are read. This mode multiplies in the pools' dtype (bf16 on
+the MXU, f32 accumulation; the probabilities are rounded to it for the
+value product, as FlashMLA does); the GQA mode multiplies in f32.
 """
 from __future__ import annotations
 
@@ -49,10 +55,9 @@ NEG_INF = -1e30
 
 
 def _stage1_kernel(tbl_ref, lens_ref, tok_ref, q_ref, k_ref, *rest,
-                   page, pps, dv, scale, share_kv):
+                   page, pps, scale, share_kv):
     if share_kv:
-        v_ref = None
-        o_ref, lse_ref, m_ref, l_ref, acc_ref = rest
+        qr_ref, kr_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref = rest
     else:
         v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref = rest
     j = pl.program_id(2)
@@ -68,17 +73,28 @@ def _stage1_kernel(tbl_ref, lens_ref, tok_ref, q_ref, k_ref, *rest,
     kv_len = lens_ref[b]
     base = (s * pps + j) * page
 
+    def scores(q, k):
+        return jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                   preferred_element_type=jnp.float32)
+
     # page-level skip: entirely past the request's live tokens (covers idle
     # slots with kv_len == 0 — their whole walk is skipped and the store
     # emits the exact empty values)
     @pl.when(base < kv_len)
     def _compute():
-        q = q_ref[0].astype(jnp.float32)                        # [Hq, dk]
-        k = k_ref[0].astype(jnp.float32)                        # [page*Hkv, dk]
-        v = k[:, :dv] if share_kv else v_ref[0].astype(jnp.float32)
-        sc = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale         # [Hq, page*Hkv]
+        if share_kv:
+            k = k_ref[0]                                        # [page, r_kv]
+            sc = (scores(q_ref[0], k)
+                  + scores(qr_ref[0], kr_ref[0])) * scale      # [Hq, page]
+            # rows past the request are zeroed, so that whatever a recycled
+            # page holds there (an inf too) meets an exact 0 weight as 0
+            row = jax.lax.broadcasted_iota(jnp.int32, (page, 1), 0)
+            v = jnp.where(base + row < kv_len, k, jnp.zeros_like(k))
+        else:
+            q = q_ref[0].astype(jnp.float32)                    # [Hq, dk]
+            k = k_ref[0].astype(jnp.float32)                    # [page*Hkv, dk]
+            v = v_ref[0].astype(jnp.float32)
+            sc = scores(q, k) * scale                           # [Hq, page*Hkv]
         # a column is live for a row when it is the row's kv head and its
         # token is inside the request (tok_ref says which token, or a
         # sentinel past every length for another head's column)
@@ -91,7 +107,8 @@ def _stage1_kernel(tbl_ref, lens_ref, tok_ref, q_ref, k_ref, *rest,
         p = jnp.where(valid, jnp.exp(sc - m_new), 0.0)
         corr = jnp.exp(m_prev - m_new)
         l_ref[...] = l_ref[...] * corr + p.sum(axis=1, keepdims=True)
-        ctx = jnp.dot(p, v, preferred_element_type=jnp.float32)  # [Hq, dv]
+        ctx = jnp.dot(p.astype(v.dtype), v,
+                      preferred_element_type=jnp.float32)       # [Hq, dv]
         acc_ref[...] = acc_ref[...] * corr + ctx
         m_ref[...] = m_new
 
@@ -125,19 +142,21 @@ def _token_of_column(Hq: int, Hkv: int, page: int) -> np.ndarray:
     return np.where(own, c // Hkv, 2 ** 30).astype(np.int32)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "num_kv_splits", "dv",
+@functools.partial(jax.jit, static_argnames=("scale", "num_kv_splits",
                                              "interpret"))
 def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
                            v_pages: jax.Array | None,
                            kv_indices: jax.Array, kv_lens: jax.Array, *,
                            scale: float, num_kv_splits: int = 1,
-                           dv: int | None = None,
+                           rope_pages: jax.Array | None = None,
                            interpret: bool = False) -> jax.Array:
-    """q: [B, Hq, dk]; k_pages: [P+1, page, Hkv, dk] (last row = zero pad
-    page); v_pages: same layout trailing dv, or None for the absorbed-MLA
-    shared pool (then ``dv`` selects the leading value columns of K);
-    kv_indices: [B, max_pages] int32 page table padded with P; kv_lens: [B]
-    int32 live tokens per request. Returns [B, Hq, dv] f32."""
+    """q: [B, Hq, dk (+ rope)]; k_pages: [P+1, page, Hkv, dk] (last row =
+    zero pad page); v_pages: same layout trailing dv, or None for the
+    absorbed-MLA pools (values are K itself, Hkv == 1), where then
+    ``rope_pages`` [P+1, page, 1, rope] holds the rotary keys that the
+    query's last ``rope`` columns score; kv_indices: [B, max_pages] int32
+    page table padded with P; kv_lens: [B] int32 live tokens per request.
+    Returns [B, Hq, dv] f32."""
     B, max_pages = kv_indices.shape
     page, Hkv, dk = k_pages.shape[1:]
     Hq = q.shape[1]
@@ -145,8 +164,10 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
     assert max_pages % S == 0, (max_pages, S)
     pps = max_pages // S
     share_kv = v_pages is None
+    assert share_kv == (rope_pages is not None)
     if share_kv:
-        assert dv is not None and Hkv == 1
+        assert Hkv == 1
+        dv = dk
     else:
         dv = v_pages.shape[-1]
 
@@ -157,7 +178,7 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
     # pages as [P+1, page*Hkv, width] views: one page is then a block whose
     # trailing dims are the array's, and K for every head is one 2-D tile
     rows = page * Hkv
-    kern = functools.partial(_stage1_kernel, page=page, pps=pps, dv=dv,
+    kern = functools.partial(_stage1_kernel, page=page, pps=pps,
                              scale=scale, share_kv=share_kv)
 
     def page_spec(width):
@@ -165,11 +186,19 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
             (1, rows, width),
             lambda b, s, j, tbl, lens: (tbl[b * max_pages + s * pps + j], 0, 0))
 
+    def row_spec(width):
+        return pl.BlockSpec((1, Hq, width),
+                            lambda b, s, j, tbl, lens: (b, 0, 0))
+
     in_specs = [pl.BlockSpec((Hq, rows), lambda b, s, j, tbl, lens: (0, 0)),
-                pl.BlockSpec((1, Hq, dk), lambda b, s, j, tbl, lens: (b, 0, 0)),
-                page_spec(dk)]
-    operands = [tok, q, k_pages.reshape(-1, rows, dk)]
-    if not share_kv:
+                row_spec(dk), page_spec(dk)]
+    operands = [tok, q[..., :dk], k_pages.reshape(-1, rows, dk)]
+    if share_kv:
+        dr = rope_pages.shape[-1]
+        assert q.shape[-1] == dk + dr, (q.shape, dk, dr)
+        in_specs += [row_spec(dr), page_spec(dr)]
+        operands += [q[..., dk:], rope_pages.reshape(-1, rows, dr)]
+    else:
         in_specs.append(page_spec(dv))
         operands.append(v_pages.reshape(-1, rows, dv))
     ins = (flat_tbl, lens, *operands)

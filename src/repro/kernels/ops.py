@@ -129,29 +129,45 @@ def flash_attention_bshd(q, k, v, *, scale, window=None, causal=True):
     return _sdpa_chunked(q, k, v, None, scale, window)
 
 
+_FALLBACK_WARNED: set = set()
+
+
 def paged_decode_attention(q, k_pages, v_pages, kv_indices, kv_lens, *,
-                           scale, num_kv_splits=1, dv=None):
+                           scale, num_kv_splits=1, rope_pages=None):
     """Split-KV paged decode attention over a page-table-indexed KV pool.
 
-    q: [B, Hq, dk]; k_pages: [P+1, page, Hkv, dk] (last row = zero pad
-    page); v_pages: same layout with trailing dv, or None for the
-    absorbed-MLA shared pool (values = leading ``dv`` key columns);
+    q: [B, Hq, dk (+ rope)]; k_pages: [P+1, page, Hkv, dk] (last row =
+    zero pad page); v_pages: same layout with trailing dv, or None for the
+    absorbed-MLA pools (values are the latent key pool itself, and
+    ``rope_pages`` [P+1, page, 1, rope] holds the rotary keys);
     kv_indices: [B, max_pages] int32 padded with P; kv_lens: [B] int32.
     Returns [B, Hq, dv] f32. Two-stage flash-decoding on TPU; jnp oracle
     elsewhere (identical masking semantics — exact zeros off the live
-    prefix, so both backends are safe over recycled pages)."""
+    prefix, so both backends are safe over recycled pages). A shape the
+    kernel does not take falls back to the oracle, with a warning once per
+    shape on a TPU."""
     use, interp = _use_pallas()
     page = k_pages.shape[1]
     dk = k_pages.shape[-1]
-    dvv = dv if v_pages is None else v_pages.shape[-1]
-    if use and dk % 128 == 0 and dvv % 128 == 0 and page % 8 == 0:
+    dv = dk if v_pages is None else v_pages.shape[-1]
+    kw = dict(scale=scale, num_kv_splits=num_kv_splits, rope_pages=rope_pages)
+    if use and dk % 128 == 0 and dv % 128 == 0 and page % 8 == 0:
         from repro.kernels import decode_attention as _da
         return _da.paged_decode_attention(
-            q, k_pages, v_pages, kv_indices, kv_lens, scale=scale,
-            num_kv_splits=num_kv_splits, dv=dv, interpret=interp)
+            q, k_pages, v_pages, kv_indices, kv_lens, interpret=interp, **kw)
+    if use and not interp:
+        shape = (q.shape, k_pages.shape, None if v_pages is None
+                 else v_pages.shape,
+                 None if rope_pages is None else rope_pages.shape)
+        if shape not in _FALLBACK_WARNED:
+            _FALLBACK_WARNED.add(shape)
+            import warnings
+            warnings.warn(
+                f"paged decode attention falls back to the jnp oracle for "
+                f"q {q.shape}, pools {shape[1:]}: the kernel needs key and "
+                f"value widths % 128 == 0 and page % 8 == 0", stacklevel=2)
     return _ref.paged_decode_attention(
-        q, k_pages, v_pages, kv_indices, kv_lens, scale=scale,
-        num_kv_splits=num_kv_splits, dv=dv)
+        q, k_pages, v_pages, kv_indices, kv_lens, **kw)
 
 
 def grouped_gemm(x: jax.Array, w: jax.Array, counts: jax.Array) -> jax.Array:

@@ -121,14 +121,16 @@ NEG_INF = -1e30
 
 
 def paged_decode_stage1(q, k_pages, v_pages, kv_indices, kv_lens, *,
-                        scale, num_kv_splits, dv=None):
+                        scale, num_kv_splits, rope_pages=None):
     """Stage 1 of split-KV paged decode attention: per-(request, split)
     partial outputs + log-sum-exp (the aiter ``mla_stage1`` shape).
 
     q: [B, Hq, dk] one decode query per request. k_pages: [P+1, page, Hkv,
     dk] paged key pool whose LAST row is the zero pad page. v_pages: same
-    layout with trailing dv — or None for the absorbed-MLA shared pool,
-    where values are the first ``dv`` key columns (Hkv == 1, one pool read).
+    layout with trailing dv — or None for the absorbed-MLA pools, where the
+    key pool (the latent c_kv, Hkv == 1) is the value pool too and
+    ``rope_pages`` [P+1, page, 1, rope] holds the rotary keys, scored by the
+    query's last ``rope`` columns (q: [B, Hq, dk + rope]).
     kv_indices: [B, max_pages] int32 per-request page table, padded with the
     pad-page index P. kv_lens: [B] int32 valid tokens per request (0 for an
     idle slot). max_pages must divide by num_kv_splits.
@@ -138,14 +140,16 @@ def paged_decode_stage1(q, k_pages, v_pages, kv_indices, kv_lens, *,
     positions contribute an exact 0 (explicit ``where``, not exp underflow),
     so recycled-page garbage can never leak into a live request."""
     B, max_pages = kv_indices.shape
-    page, Hkv, dk = k_pages.shape[1:]
+    Hkv = k_pages.shape[2]
     Hq = q.shape[1]
     G = Hq // Hkv
     S = num_kv_splits
     assert max_pages % S == 0, (max_pages, S)
     if v_pages is None:
-        assert dv is not None and Hkv == 1
-        v_pages = k_pages[..., :dv]
+        assert Hkv == 1
+        v_pages = k_pages
+        k_pages = jnp.concatenate([k_pages, rope_pages], axis=-1)
+    page, _, dk = k_pages.shape[1:]
     dv = v_pages.shape[-1]
     k = k_pages[kv_indices].reshape(B, max_pages * page, Hkv, dk)
     v = v_pages[kv_indices].reshape(B, max_pages * page, Hkv, dv)
@@ -156,7 +160,9 @@ def paged_decode_stage1(q, k_pages, v_pages, kv_indices, kv_lens, *,
     s = jnp.where(valid[:, None, None], s, NEG_INF)
     # split the KV axis: [B, Hkv, G, S, pps*page]
     sc = s.reshape(B, Hkv, G, S, -1)
-    vc = v.reshape(B, S, -1, Hkv, dv).astype(jnp.float32)
+    # values off the live prefix are zeroed too: 0 × inf would be NaN
+    vc = jnp.where(valid[:, :, None, None], v.astype(jnp.float32), 0.0)
+    vc = vc.reshape(B, S, -1, Hkv, dv)
     mc = valid.reshape(B, 1, 1, S, -1)
     m = sc.max(-1)                                          # [B, Hkv, G, S]
     p = jnp.where(mc, jnp.exp(sc - m[..., None]), 0.0)
@@ -184,13 +190,13 @@ def paged_decode_stage2(o_parts, lse):
 
 
 def paged_decode_attention(q, k_pages, v_pages, kv_indices, kv_lens, *,
-                           scale, num_kv_splits=1, dv=None):
+                           scale, num_kv_splits=1, rope_pages=None):
     """Two-stage split-KV paged decode attention over a page-table-indexed
     KV pool — the jnp semantics of record for
     ``kernels/decode_attention.py``. Returns [B, Hq, dv] f32."""
     o, lse = paged_decode_stage1(q, k_pages, v_pages, kv_indices, kv_lens,
                                  scale=scale, num_kv_splits=num_kv_splits,
-                                 dv=dv)
+                                 rope_pages=rope_pages)
     return paged_decode_stage2(o, lse)
 
 

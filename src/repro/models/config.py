@@ -31,12 +31,26 @@ class AttnSpec:
 
 
 @dataclasses.dataclass(frozen=True)
+class YarnScaling:
+    """YaRN rope scaling, as a DeepSeek-V2/V3 ``rope_scaling`` of type
+    "yarn" states it (models/layers.py ``yarn_frequencies``, ``yarn_mscale``)."""
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+@dataclasses.dataclass(frozen=True)
 class MLASpec:
     q_lora_rank: int
     kv_lora_rank: int
     qk_nope_dim: int
     qk_rope_dim: int
     v_head_dim: int
+    # rope scaling of the rotary key/query dims; None = plain rope
+    rope_scaling: YarnScaling | None = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -86,6 +100,12 @@ class MoESpec:
     # at every rebalance boundary, so exact counting holds for any window
     # below ~16M routed tokens per expert.
     track_expert_heat: bool = False
+    # The logical experts this chip holds on the one-chip path: one rank's
+    # slice of an EpPlacement (core/placement.rank_experts). The router
+    # still routes over all num_experts; the layer computes only the held
+    # experts' part of the result and the expert-stacked weights hold
+    # len(held_experts) rows. None = every expert is held here.
+    held_experts: tuple[int, ...] | None = None
 
 
 @dataclasses.dataclass(frozen=True)

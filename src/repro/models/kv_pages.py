@@ -22,13 +22,12 @@ the request completes. The allocator is a LIFO free list — recycling hot
 pages quickly is deliberate, it stresses the masking contract that the
 paged-KV tests pin.
 
-GQA layers keep separate K and V pools; absorbed-MLA decode uses ONE pool
-per layer holding [ckv | k_rope] rows (Hkv == 1) — values are the leading
-``kv_lora_rank`` columns, so each page is read from HBM exactly once.
+GQA layers keep separate K and V pools; absorbed-MLA decode keeps the latent
+``ckv`` (keys and values both, Hkv == 1) and the shared rotary key ``krope``
+in two pools per layer, so each page's latent row is read from HBM once and
+neither pool is padded to the other's width.
 """
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -109,12 +108,15 @@ def paged_kv_pool_spec(cfg: ArchConfig, num_pages: int, page_size: int):
 
 
 def paged_mla_pool_spec(cfg: ArchConfig, num_pages: int, page_size: int):
-    """Absorbed-MLA per-layer pool: {"kv"} [num_pages+1, page, 1, r_kv+rope]
-    holding [ckv | k_rope] — one shared pool, values = leading r_kv cols."""
+    """Absorbed-MLA per-layer pools: {"ckv"} [num_pages+1, page, 1, r_kv]
+    (the latent, keys and values both) and {"krope"} [.., 1, rope] (the
+    shared rotary key). Two arrays, so each is read at its own width."""
     m = cfg.mla
-    width = m.kv_lora_rank + m.qk_rope_dim
-    return {"kv": ParamSpec((num_pages + 1, page_size, 1, width), cfg.dtype,
-                            (None, None, None, None))}
+
+    def pool(width):
+        return ParamSpec((num_pages + 1, page_size, 1, width), cfg.dtype,
+                         (None, None, None, None))
+    return {"ckv": pool(m.kv_lora_rank), "krope": pool(m.qk_rope_dim)}
 
 
 def write_token(pool: jax.Array, new: jax.Array, page_tbl: jax.Array,
@@ -136,27 +138,29 @@ def write_token(pool: jax.Array, new: jax.Array, page_tbl: jax.Array,
     return pool.at[page_ids, offs].set(new.astype(pool.dtype))
 
 
-def decode_attention(mesh, q, k_pages, v_pages, page_tbl, kv_lens, **kw):
+def decode_attention(mesh, q, k_pages, v_pages, page_tbl, kv_lens, *,
+                     rope_pages=None, **kw):
     """``kernels.ops.paged_decode_attention`` on a mesh: XLA cannot
     partition a Pallas kernel, so under a mesh each device runs it over its
     own share of the requests (the batch axes of the sharding rules)
     against the whole page pool. Pools sharded over a "model" axis are
     gathered first. Without a mesh it is the plain call."""
     from repro.kernels import ops as KOPS
-    attend = functools.partial(KOPS.paged_decode_attention, **kw)
-    if v_pages is None:
-        attend = functools.partial(attend, v_pages=None)
-        pools = (k_pages,)
-    else:
-        pools = (k_pages, v_pages)
+    pools = {k: v for k, v in (("k_pages", k_pages), ("v_pages", v_pages),
+                               ("rope_pages", rope_pages)) if v is not None}
+
+    def attend(q, t, n, *vals):
+        p = dict(zip(pools, vals))
+        return KOPS.paged_decode_attention(
+            q, p["k_pages"], p.get("v_pages"), t, n,
+            rope_pages=p.get("rope_pages"), **kw)
     if mesh is None or mesh.empty:
-        return attend(q, *pools, kv_indices=page_tbl, kv_lens=kv_lens)
+        return attend(q, page_tbl, kv_lens, *pools.values())
     rows = logical_to_pspec(ParamSpec(q.shape[:1], axes=("batch",)), mesh,
                             DEFAULT_RULES)
     return jax.shard_map(
-        lambda q, t, n, *pools: attend(q, *pools, kv_indices=t, kv_lens=n),
-        mesh=mesh, in_specs=(rows, rows, rows) + (P(),) * len(pools),
-        out_specs=rows)(q, page_tbl, kv_lens, *pools)
+        attend, mesh=mesh, in_specs=(rows, rows, rows) + (P(),) * len(pools),
+        out_specs=rows)(q, page_tbl, kv_lens, *pools.values())
 
 
 def dense_equiv_tokens(batch: int, max_len: int) -> int:

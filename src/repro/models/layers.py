@@ -1,6 +1,8 @@
 """Shared primitive layers: norms, RoPE, gated FFNs, embeddings."""
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -26,19 +28,54 @@ def rope_frequencies(rot_dim: int, base: float) -> np.ndarray:
     return 1.0 / (base ** (np.arange(0, rot_dim, 2, dtype=np.float64) / rot_dim))
 
 
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's magnitude correction 0.1 · mscale · ln(factor) + 1 (1 when
+    the context is not stretched)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_frequencies(rot_dim: int, base: float, y) -> np.ndarray:
+    """YaRN inverse frequencies (DeepSeek-V3's ``rope_scaling``, ``y`` a
+    ``YarnScaling``): pair i keeps base^(-2i/D) below the correction range
+    of ``beta_fast`` rotations over the original context, takes it divided
+    by ``factor`` above that of ``beta_slow``, and ramps linearly between."""
+    extra = rope_frequencies(rot_dim, base)
+
+    def corr_dim(rotations):
+        return (rot_dim * math.log(y.original_max_position
+                                   / (rotations * 2 * math.pi))
+                / (2 * math.log(base)))
+    lo = max(math.floor(corr_dim(y.beta_fast)), 0)
+    hi = min(math.ceil(corr_dim(y.beta_slow)), rot_dim - 1)
+    if lo == hi:
+        hi += 0.001
+    ramp = np.clip((np.arange(rot_dim // 2) - lo) / (hi - lo), 0.0, 1.0)
+    return extra / y.factor * ramp + extra * (1.0 - ramp)
+
+
 def apply_rope(x: jax.Array, positions: jax.Array, base: float,
-               fraction: float = 1.0) -> jax.Array:
+               fraction: float = 1.0, scaling=None) -> jax.Array:
     """x: [B, S, H, D]; positions: [B, S]. Rotates the first
-    ``fraction * D`` components (chatglm3's 2d RoPE == fraction 0.5)."""
+    ``fraction * D`` components (chatglm3's 2d RoPE == fraction 0.5).
+    ``scaling`` (a ``YarnScaling``) takes YaRN's frequencies and scales
+    cos and sin by mscale(factor, mscale) / mscale(factor, mscale_all_dim)."""
     B, S, H, D = x.shape
     rot = int(D * fraction)
     rot -= rot % 2
     if rot == 0:
         return x
-    inv = jnp.asarray(rope_frequencies(rot, base), jnp.float32)     # [rot/2]
+    if scaling is None:
+        freqs, amp = rope_frequencies(rot, base), 1.0
+    else:
+        freqs = yarn_frequencies(rot, base, scaling)
+        amp = (yarn_mscale(scaling.factor, scaling.mscale)
+               / yarn_mscale(scaling.factor, scaling.mscale_all_dim))
+    inv = jnp.asarray(freqs, jnp.float32)                          # [rot/2]
     ang = positions.astype(jnp.float32)[:, :, None] * inv[None, None, :]  # [B,S,rot/2]
     cos = jnp.cos(ang)[:, :, None, :]
     sin = jnp.sin(ang)[:, :, None, :]
+    if amp != 1.0:
+        cos, sin = cos * amp, sin * amp
     xr, xp = x[..., :rot], x[..., rot:]
     x1, x2 = xr[..., 0::2], xr[..., 1::2]
     y1 = x1 * cos - x2 * sin

@@ -7,6 +7,11 @@ Decode: the *absorbed* formulation — cache only [c_kv (r_kv) | k_rope] per
 token (the whole point of MLA: DeepSeek-V3 caches 512+64 floats/token instead
 of 128 heads x 128). W_uk is absorbed into the query and W_uv into the output
 projection, so scores are taken directly against the compressed cache.
+
+Rope: with ``MLASpec.rope_scaling`` (DeepSeek-V3's YaRN) the rotary dims take
+YaRN's frequencies, and the softmax scale (nope + rope)^-0.5 is multiplied
+by mscale(factor, mscale_all_dim)² (``softmax_scale``), in prefill and in
+paged decode alike.
 """
 from __future__ import annotations
 
@@ -16,7 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.models.config import ArchConfig
-from repro.models.layers import apply_rope, rmsnorm
+from repro.models.layers import apply_rope, rmsnorm, yarn_mscale
 from repro.parallel.sharding import ParamSpec, constrain
 
 
@@ -68,14 +73,31 @@ def _dot32(eq, *ops):
     return jnp.einsum(eq, *ops, preferred_element_type=jnp.float32)
 
 
+def softmax_scale(cfg: ArchConfig) -> float:
+    """(nope + rope)^-0.5, times mscale(factor, mscale_all_dim)² under YaRN
+    with ``mscale_all_dim`` set (DeepSeek-V3: × 1.3689² at factor 40)."""
+    m = cfg.mla
+    scale = (m.qk_nope_dim + m.qk_rope_dim) ** -0.5
+    y = m.rope_scaling
+    if y is not None and y.mscale_all_dim:
+        scale *= yarn_mscale(y.factor, y.mscale_all_dim) ** 2
+    return scale
+
+
+def _rope(x, positions, cfg):
+    """Rotary part [B, S, H, rope] at ``positions``, with the config's rope
+    scaling."""
+    return apply_rope(x, positions, cfg.attn.rope_base, 1.0,
+                      scaling=cfg.mla.rope_scaling)
+
+
 def _q_proj(p, x, cfg, positions):
     m = cfg.mla
     B, S, _ = x.shape
     q = rmsnorm(x @ p["wq_a"], p["q_norm"], cfg.norm_eps)
     q = jnp.einsum("bsr,rhk->bshk", q, p["wq_b"])     # [B,S,H,nope+rope]
     q_nope, q_rope = q[..., :m.qk_nope_dim], q[..., m.qk_nope_dim:]
-    q_rope = apply_rope(q_rope, positions, cfg.attn.rope_base, 1.0)
-    return q_nope, q_rope
+    return q_nope, _rope(q_rope, positions, cfg)
 
 
 def _mla_chunked(p, q_nope, q_rope, ckv, k_rope, scale, out_dtype, chunk=1024):
@@ -128,33 +150,33 @@ def _mla_chunked(p, q_nope, q_rope, ckv, k_rope, scale, out_dtype, chunk=1024):
 
 def paged_mla_attention(p, x, cfg: ArchConfig, mesh, pool, page_tbl, kv_lens,
                         active, *, num_kv_splits: int = 1):
-    """One-token absorbed-MLA decode against the paged latent pool.
+    """One-token absorbed-MLA decode against the paged latent pools.
 
-    pool: {"kv"} [P+1, page, 1, r_kv+rope] holding [ckv | k_rope] — ONE
-    shared pool (models/kv_pages.paged_mla_pool_spec): the query is
-    [q_absorbed | q_rope] against the full row and values are the leading
-    r_kv columns, so each page is read from HBM exactly once
-    (share_kv mode of kernels/decode_attention). Returns (y, new_pool)."""
+    pool: {"ckv"} [P+1, page, 1, r_kv] and {"krope"} [P+1, page, 1, rope]
+    (models/kv_pages.paged_mla_pool_spec): the query is [q_absorbed |
+    q_rope], scored against c_kv and k_rope, and the values are c_kv, so
+    each page's latent row is read from HBM once (share_kv mode of
+    kernels/decode_attention). Returns (y, new_pool)."""
     from repro.models.kv_pages import decode_attention, write_token
     m = cfg.mla
     positions = kv_lens[:, None]                           # [B, 1]
-    scale = (m.qk_nope_dim + m.qk_rope_dim) ** -0.5
     kv = x @ p["wkv_a"]                                    # [B, 1, r_kv+rope]
     ckv = rmsnorm(kv[..., :m.kv_lora_rank], p["kv_norm"], cfg.norm_eps)
-    k_rope = apply_rope(kv[..., None, m.kv_lora_rank:], positions,
-                        cfg.attn.rope_base, 1.0)[:, :, 0]  # [B, 1, rope]
+    k_rope = _rope(kv[..., None, m.kv_lora_rank:], positions, cfg)[:, :, 0]
     q_nope, q_rope = _q_proj(p, x, cfg, positions)
-    row = jnp.concatenate([ckv, k_rope], axis=-1)[:, 0][:, None]  # [B,1,width]
-    kvp = write_token(pool["kv"], row, page_tbl, kv_lens)
+    # [B, 1, width] rows are [B, Hkv=1, width] tokens for the scatter
+    ckv_p = write_token(pool["ckv"], ckv, page_tbl, kv_lens)
+    kr_p = write_token(pool["krope"], k_rope, page_tbl, kv_lens)
     q_abs = jnp.einsum("bqhk,rhk->bqhr", q_nope, p["wk_b"])  # absorb W_uk
     qcat = jnp.concatenate([q_abs, q_rope], axis=-1)[:, 0]   # [B, H, r+rope]
     eff = kv_lens + active
-    ctx = decode_attention(mesh, qcat, kvp, None, page_tbl, eff,
-                           scale=scale, num_kv_splits=num_kv_splits,
-                           dv=m.kv_lora_rank)                # [B, H, r] f32
+    with jax.named_scope("paged_decode"):
+        ctx = decode_attention(mesh, qcat, ckv_p, None, page_tbl, eff,
+                               rope_pages=kr_p, scale=softmax_scale(cfg),
+                               num_kv_splits=num_kv_splits)  # [B, H, r] f32
     o = jnp.einsum("bhr,rhk->bhk", ctx.astype(x.dtype), p["wv_b"])  # absorb W_uv
     y = jnp.einsum("bqhk,hkd->bqd", o[:, None], p["wo"])
-    return y, {"kv": kvp}
+    return y, {"ckv": ckv_p, "krope": kr_p}
 
 
 def mla_attention(p, x, cfg: ArchConfig, mesh, *, positions=None,
@@ -166,12 +188,12 @@ def mla_attention(p, x, cfg: ArchConfig, mesh, *, positions=None,
         positions = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
         if cache is not None:
             positions = positions + cache.length
-    scale = (m.qk_nope_dim + m.qk_rope_dim) ** -0.5
+    scale = softmax_scale(cfg)
 
     kv = x @ p["wkv_a"]                                # [B,S,r_kv+rope]
     ckv = rmsnorm(kv[..., :m.kv_lora_rank], p["kv_norm"], cfg.norm_eps)
-    k_rope = apply_rope(kv[..., None, m.kv_lora_rank:], positions,
-                        cfg.attn.rope_base, 1.0)[:, :, 0]   # [B,S,rope]
+    k_rope = _rope(kv[..., None, m.kv_lora_rank:], positions,
+                   cfg)[:, :, 0]                       # [B,S,rope]
     q_nope, q_rope = _q_proj(p, x, cfg, positions)
 
     if cache is None:

@@ -34,8 +34,11 @@ from repro.parallel.sharding import ParamSpec
 
 def _num_weight_rows(m) -> int:
     """Leading dim of the expert-stacked weights under the param-layout
-    mode: physical slot count in adopt-once mode (== E when placement is
-    None or identity), logical E otherwise."""
+    mode: the held experts' count when the chip holds a slice
+    (``held_experts``), physical slot count in adopt-once mode (== E when
+    placement is None or identity), logical E otherwise."""
+    if m.held_experts is not None:
+        return len(m.held_experts)
     if m.params_physical and m.placement is not None:
         return m.placement.num_slots
     return m.num_experts
@@ -124,17 +127,23 @@ def _expert_ffn(group, y3d, counts, w1, w3, w2, act, tp_axis):
     return out
 
 
-def moe_block(p, x, cfg: ArchConfig, mesh, *, with_heat: bool = False):
+def moe_block(p, x, cfg: ArchConfig, mesh, *, with_heat: bool = False,
+              live=None):
     """x: [B, S, D] -> (y [B, S, D], aux_loss scalar).
 
     With ``with_heat=True`` additionally returns the per-logical-expert
     routed-token histogram [E] (replicated), the signal the EPLB rebalancer
-    consumes (runtime/server.py folds it into the decode state)."""
+    consumes (runtime/server.py folds it into the decode state). With
+    ``live`` ([B] 0/1, the rows that carry a request) it last returns the
+    held experts' load f32[2] (``_moe_dense_fallback``); that is the
+    one-chip path's alone, as is ``MoESpec.held_experts``."""
     m = cfg.moe
 
     def _fallback():
-        y, heat = _moe_dense_fallback(p, x, cfg, with_heat=True)
-        return (y, jnp.float32(0), heat) if with_heat else (y, jnp.float32(0))
+        y, heat, *load = _moe_dense_fallback(p, x, cfg, with_heat=True,
+                                             live=live)
+        return ((y, jnp.float32(0)) + ((heat,) if with_heat else ())
+                + tuple(load))
 
     if mesh is None or mesh.empty:
         return _fallback()
@@ -149,6 +158,10 @@ def moe_block(p, x, cfg: ArchConfig, mesh, *, with_heat: bool = False):
     phys = m.placement.num_slots if m.placement is not None else m.num_experts
     if N <= 1 or phys % N != 0:
         return _fallback()
+    if m.held_experts is not None or live is not None:
+        raise ValueError(
+            "held_experts and the held experts' load belong to the one-chip "
+            "path; under an EP mesh each rank holds its placement's slice")
     B, S, D = x.shape
     # tokens per EP rank (static)
     b_div = math.prod(mesh.shape[a] for a in b_axes) if b_axes else 1
@@ -242,13 +255,25 @@ def moe_block(p, x, cfg: ArchConfig, mesh, *, with_heat: bool = False):
     res = fn(x, p["router"], w1, w3, w2, sel)
     y, aux = res[0], res[1]
     if m.shared_experts:
-        y = y + ffn_apply(p["shared"], x, cfg.act)
+        with jax.named_scope("moe.shared"):
+            y = y + ffn_apply(p["shared"], x, cfg.act)
     return (y, aux, res[2]) if with_heat else (y, aux)
 
 
-def _moe_dense_fallback(p, x, cfg: ArchConfig, *, with_heat: bool = False):
-    """Reference MoE for meshless smoke tests: dense routing, no EP comms.
-    Semantics identical to the EP path (same router, same expert math)."""
+def _moe_dense_fallback(p, x, cfg: ArchConfig, *, with_heat: bool = False,
+                        live=None):
+    """Dense MoE over the experts this chip holds (``MoESpec.held_experts``;
+    all E by default), the one-chip path and the meshless reference: the
+    router routes over all E experts, each held expert's SwiGLU runs over
+    every token (named scope ``moe.experts``), weighted by its gate (zero
+    where the token did not choose it), then the shared expert (scope
+    ``moe.shared``). Holding all E it is the whole layer, with the EP
+    path's semantics (same router, same expert math); holding one rank's
+    slice it is that rank's part of the layer, plus the shared expert.
+
+    -> y, or (y, heat [E]) with ``with_heat``; with ``live`` ([B] 0/1) one
+    more entry, the held experts' load f32[2]: routed (token, expert)
+    pairs of live rows on a held expert, and held experts with >= 1."""
     m = cfg.moe
     B, S, D = x.shape
     xt = x.reshape(-1, D)
@@ -260,18 +285,29 @@ def _moe_dense_fallback(p, x, cfg: ArchConfig, *, with_heat: bool = False):
         # slot-ordered weights to logical order (primary replica)
         w1, w3, w2 = (collapse_expert_params(w, m.placement)
                       for w in (w1, w3, w2))
-    h_g = jnp.einsum("td,edf->tef", xt, w1)
-    h_u = jnp.einsum("td,edf->tef", xt, w3)
-    h = (jax.nn.silu(h_g.astype(jnp.float32)) * h_u.astype(jnp.float32)).astype(x.dtype)
-    y_all = jnp.einsum("tef,efd->ted", h, w2)            # [T, E, D]
-    oh = jax.nn.one_hot(r.topk_idx, m.num_experts, dtype=jnp.float32)
-    gate = jnp.einsum("tk,tke->te", r.topk_weights, oh)  # [T, E]
-    y = jnp.einsum("ted,te->td", y_all.astype(jnp.float32), gate).astype(x.dtype)
+    held = (range(m.num_experts) if m.held_experts is None
+            else m.held_experts)
+    oh = (r.topk_idx[..., None]
+          == jnp.asarray(held, jnp.int32)).astype(jnp.float32)  # [T, K, Eh]
+    with jax.named_scope("moe.experts"):
+        h_g = jnp.einsum("td,edf->tef", xt, w1)
+        h_u = jnp.einsum("td,edf->tef", xt, w3)
+        h = (jax.nn.silu(h_g.astype(jnp.float32)) * h_u.astype(jnp.float32)).astype(x.dtype)
+        y_all = jnp.einsum("tef,efd->ted", h, w2)        # [T, Eh, D]
+        gate = jnp.einsum("tk,tke->te", r.topk_weights, oh)  # [T, Eh]
+        y = jnp.einsum("ted,te->td", y_all.astype(jnp.float32), gate).astype(x.dtype)
     y = y.reshape(B, S, D)
     if m.shared_experts:
-        y = y + ffn_apply(p["shared"], x, cfg.act)
+        with jax.named_scope("moe.shared"):
+            y = y + ffn_apply(p["shared"], x, cfg.act)
+    if not with_heat and live is None:
+        return y
+    out = (y,)
     if with_heat:
-        heat = jnp.zeros((m.num_experts,), jnp.float32).at[
-            r.topk_idx.reshape(-1)].add(1.0, mode="drop")
-        return y, heat
-    return y
+        out += (jnp.zeros((m.num_experts,), jnp.float32).at[
+            r.topk_idx.reshape(-1)].add(1.0, mode="drop"),)
+    if live is not None:
+        rows = jnp.einsum("tke,t->e", oh,
+                          jnp.repeat(live.astype(jnp.float32), S))
+        out += (jnp.stack([rows.sum(), (rows > 0).sum().astype(jnp.float32)]),)
+    return out

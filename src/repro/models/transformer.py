@@ -55,23 +55,24 @@ def layer_spec(cfg: ArchConfig, *, moe_layer: bool):
     return sp
 
 
-def _ffn_half(p, x, cfg: ArchConfig, mesh, new_cache, with_heat):
+def _ffn_half(p, x, cfg: ArchConfig, mesh, new_cache, with_heat, live=None):
     """The FFN half of a layer: ln2, then the MoE block (named scope
-    ``moe``) or the dense FFN, plus the residual. -> (x, new_cache, aux)."""
+    ``moe``) or the dense FFN, plus the residual. -> (x, new_cache, aux);
+    aux is the aux loss, followed by the expert heat with ``with_heat`` and
+    the held experts' load with ``live`` (a tuple when either is asked)."""
+    extra = with_heat or live is not None
     if "moe" in p:
         with jax.named_scope("moe"):
             h = rmsnorm(x, p["ln2"], cfg.norm_eps)
-            if with_heat:
-                f, aux, heat = MOE.moe_block(p["moe"], h, cfg, mesh,
-                                             with_heat=True)
-                return x + f, new_cache, (aux, heat)
-            f, aux = MOE.moe_block(p["moe"], h, cfg, mesh)
-            return x + f, new_cache, aux
+            f, *aux = MOE.moe_block(p["moe"], h, cfg, mesh,
+                                    with_heat=with_heat, live=live)
+            return x + f, new_cache, tuple(aux) if extra else aux[0]
     h = rmsnorm(x, p["ln2"], cfg.norm_eps)
     f, aux = ffn_apply(p["ffn"], h, cfg.act), jnp.float32(0)
-    if with_heat:
+    if extra:
         E = cfg.moe.num_experts if cfg.moe else 1
-        return x + f, new_cache, (aux, jnp.zeros((E,), jnp.float32))
+        aux = ((aux,) + ((jnp.zeros((E,), jnp.float32),) if with_heat else ())
+               + ((jnp.zeros((2,), jnp.float32),) if live is not None else ()))
     return x + f, new_cache, aux
 
 
@@ -95,11 +96,13 @@ def layer_apply(p, x, cfg: ArchConfig, mesh, *, cache=None, window="cfg",
 
 
 def paged_layer_apply(p, x, cfg: ArchConfig, mesh, pool, page_tbl, kv_lens,
-                      active, *, num_kv_splits: int, with_heat=False):
+                      active, *, num_kv_splits: int, with_heat=False,
+                      with_load=False):
     """layer_apply's paged-decode twin: attention runs against the paged KV
     pool (kernels/decode_attention via ops); the FFN/MoE half is identical.
     -> (x, new_pool, aux) with the same aux contract and scopes as
-    layer_apply."""
+    layer_apply; ``with_load`` appends the held experts' load of the
+    ``active`` rows to aux (``_ffn_half``)."""
     with jax.named_scope("attn"):
         h = rmsnorm(x, p["ln1"], cfg.norm_eps)
         if cfg.attn and cfg.attn.kind == "mla":
@@ -111,7 +114,8 @@ def paged_layer_apply(p, x, cfg: ArchConfig, mesh, pool, page_tbl, kv_lens,
                 p["attn"], h, cfg, mesh, pool, page_tbl, kv_lens, active,
                 num_kv_splits=num_kv_splits)
         x = x + a
-    return _ffn_half(p, x, cfg, mesh, new_pool, with_heat)
+    return _ffn_half(p, x, cfg, mesh, new_pool, with_heat,
+                     live=active if with_load else None)
 
 
 def _stack(specs, n: int):
@@ -300,6 +304,12 @@ def lm_paged_decode_state_spec(cfg: ArchConfig, num_pages: int,
             # same logical-[E] heat contract as the dense decode state
             st["expert_heat"] = ParamSpec((cfg.moe.num_experts,), jnp.float32,
                                           (None,), init="zeros")
+        if cfg.moe.held_experts is not None:
+            # the last step's held-expert load, summed over the MoE layers:
+            # [live routed rows on held experts, held experts hit] — the
+            # engine reads it back with the step's tokens
+            st["held_load"] = ParamSpec((2,), jnp.float32, (None,),
+                                        init="zeros")
     return st
 
 
@@ -334,16 +344,23 @@ def lm_paged_decode_step(params, state, batch, cfg: ArchConfig, mesh):
         x, new_state["dense"], _ = _scan_stack(
             body, x, params["dense_stack"], state["dense"], cfg, remat=False)
     if "moe" in state:
-        if "expert_heat" in state:
-            def body_heat(x, p, c):
+        heat, load = "expert_heat" in state, "held_load" in state
+        if heat or load:
+            def body_aux(x, p, c):
                 return paged_layer_apply(p, x, cfg, mesh, c, tbl, lens, act,
-                                         num_kv_splits=splits, with_heat=True)
-            aux0 = (jnp.float32(0),
-                    jnp.zeros((cfg.moe.num_experts,), jnp.float32))
-            x, new_state["moe"], (_, heat) = _scan_stack(
-                body_heat, x, params["moe_stack"], state["moe"], cfg,
+                                         num_kv_splits=splits, with_heat=heat,
+                                         with_load=load)
+            aux0 = ((jnp.float32(0),)
+                    + ((jnp.zeros((cfg.moe.num_experts,), jnp.float32),)
+                       if heat else ())
+                    + ((jnp.zeros((2,), jnp.float32),) if load else ()))
+            x, new_state["moe"], aux = _scan_stack(
+                body_aux, x, params["moe_stack"], state["moe"], cfg,
                 remat=False, aux0=aux0)
-            new_state["expert_heat"] = state["expert_heat"] + heat
+            if heat:
+                new_state["expert_heat"] = state["expert_heat"] + aux[1]
+            if load:
+                new_state["held_load"] = aux[-1]
         else:
             x, new_state["moe"], _ = _scan_stack(
                 body, x, params["moe_stack"], state["moe"], cfg, remat=False)

@@ -77,7 +77,8 @@ from repro.runtime.fault import (DegradedRecovery, FaultDetector,
                                  PreemptionGuard, StragglerWatchdog)
 from repro.runtime.steps import (make_paged_serve_step, make_serve_step,
                                  paged_serve_state_specs, serve_state_specs)
-from repro.runtime.telemetry import NULL_SERIES, NULL_TRACER, json_safe
+from repro.runtime.telemetry import (NULL_SERIES, NULL_TRACER, json_safe,
+                                     recording)
 
 
 @dataclasses.dataclass
@@ -897,7 +898,13 @@ class ContinuousDecodeServer(DecodeServer):
                 tok, self.state = self.step(self.params, self.state, feed)
                 jax.block_until_ready(tok)
             now = time.perf_counter()
-            with self.tracer.span("serve.readback"):
+            with self.tracer.span("serve.readback") as span:
+                if "held_load" in self.state and recording(self.tracer):
+                    # the step's held-expert load rides on the tokens'
+                    # readback (the step is already done)
+                    load = np.asarray(self.state["held_load"])
+                    span.set_metadata(local_rows=int(load[0]),
+                                      experts_hit=int(load[1]))
                 sched.observe(np.asarray(tok), now)
             if record:
                 # pure host state — engine occupancy at this boundary

@@ -186,6 +186,12 @@ class NullTracer:
 NULL_TRACER = NullTracer()
 
 
+def recording(tracer) -> bool:
+    """Whether ``tracer``'s spans are recorded anywhere: an enabled tracer,
+    or a profiler session. Args that cost a readback are set only then."""
+    return tracer.enabled or TraceAnnotation.is_enabled()
+
+
 class TimeSeries:
     """Append-only recorder of per-window metric rows (plain dicts)."""
 

@@ -147,24 +147,29 @@ def test_kernel_matches_oracle_gqa(splits):
 
 @pytest.mark.parametrize("splits", [1, 2, 4])
 def test_kernel_matches_oracle_mla_shared_pool(splits):
-    """Absorbed-MLA share-kv mode: ONE pool of [ckv | k_rope] rows
-    (Hkv == 1), values = leading kv_lora_rank columns, v_pages=None."""
+    """Absorbed-MLA share-kv mode: the latent pool (Hkv == 1, v_pages=None)
+    is keys and values both, and the rope pool's keys are scored by the
+    query's last columns — against the dense softmax over [ckv | k_rope]
+    keys and ckv values."""
     rng = np.random.RandomState(SEED + 13)
-    B, dk, dv, page, max_pages = 3, 24, 16, 4, 4   # dk = r_kv 16 + rope 8
+    B, r, dr, page, max_pages = 3, 16, 8, 4, 4
     Hq = 4
     lens = np.array([7, 13, 0], np.int32)
-    scale = dk ** -0.5
-    q, kp, _, tbl, kd, vd = _paged_case(
-        rng, B=B, Hkv=1, G=Hq, dk=dk, dv=dv, page=page,
+    scale = (r + dr) ** -0.5
+    q, kp, _, tbl, kd, _ = _paged_case(
+        rng, B=B, Hkv=1, G=Hq, dk=r + dr, dv=r, page=page,
         max_pages=max_pages, lens=lens, share_kv=True)
+    ckv, krope = kp[..., :r], kp[..., r:]
+    args = (jnp.asarray(q), jnp.asarray(ckv), None, jnp.asarray(tbl),
+            jnp.asarray(lens))
     got = DA.paged_decode_attention(
-        jnp.asarray(q), jnp.asarray(kp), None, jnp.asarray(tbl),
-        jnp.asarray(lens), scale=scale, num_kv_splits=splits, dv=dv,
-        interpret=True)
+        *args, scale=scale, num_kv_splits=splits,
+        rope_pages=jnp.asarray(krope), interpret=True)
     ref = KREF.paged_decode_attention(
-        jnp.asarray(q), jnp.asarray(kp), None, jnp.asarray(tbl),
-        jnp.asarray(lens), scale=scale, num_kv_splits=splits, dv=dv)
-    dense = _dense_softmax_ref(q, kd, vd, lens, scale)
+        *args, scale=scale, num_kv_splits=splits,
+        rope_pages=jnp.asarray(krope))
+    dense = _dense_softmax_ref(q, kd, kd[..., :r], lens, scale)
+    # the kernel rounds probabilities to the pool's dtype (f32 here)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
     np.testing.assert_allclose(np.asarray(got), dense, rtol=2e-5, atol=2e-5)

@@ -111,13 +111,29 @@ def test_paged_decode_gqa(one_chip):
 
 
 def test_paged_decode_shared_pool(one_chip):
-    # absorbed-MLA form: one pool row [ckv | k_rope], values = leading dv
+    # absorbed-MLA form: latent pool (keys and values) + 64-wide rope pool
     B, P, page, max_pages = 8, 256, 8, 32
     fn = functools.partial(da.paged_decode_attention, v_pages=None,
-                           scale=192 ** -0.5, num_kv_splits=4, dv=512)
-    _compile(lambda q, k, t, n: fn(q, k, kv_indices=t, kv_lens=n), one_chip,
-             ((B, 16, 640), BF16), ((P + 1, page, 1, 640), BF16),
-             ((B, max_pages), I32), ((B,), I32))
+                           scale=192 ** -0.5, num_kv_splits=4)
+    _compile(lambda q, c, r, t, n: fn(q, c, kv_indices=t, kv_lens=n,
+                                      rope_pages=r), one_chip,
+             ((B, 16, 576), BF16), ((P + 1, page, 1, 512), BF16),
+             ((P + 1, page, 1, 64), BF16), ((B, max_pages), I32), ((B,), I32))
+
+
+def test_paged_decode_mla_serving_shape(one_chip):
+    # the dsv3-longgen cell: 128 slots, DeepSeek-V3's 128 heads over the
+    # 576-wide latent row (512 + 64), page 64, max_len 2304 -> 36 pages
+    B, page, max_pages = 128, 64, 36
+    P = B * max_pages
+    fn = functools.partial(da.paged_decode_attention, v_pages=None,
+                           scale=192 ** -0.5, num_kv_splits=4)
+    text = _compile(lambda q, c, r, t, n: fn(q, c, kv_indices=t, kv_lens=n,
+                                             rope_pages=r), one_chip,
+                    ((B, 128, 576), BF16), ((P + 1, page, 1, 512), BF16),
+                    ((P + 1, page, 1, 64), BF16), ((B, max_pages), I32),
+                    ((B,), I32))
+    assert "paged_decode_stage1" in text and "paged_decode_stage2" in text
 
 
 def test_flash_attention(one_chip):
