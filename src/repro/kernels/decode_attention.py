@@ -8,7 +8,7 @@ aiter's ``mla_decode_fwd`` (SNIPPETS.md Snippet 1):
   stage 1 — grid (B, num_kv_splits, pages_per_split), pages innermost. The
     flattened page table is scalar-prefetched into SMEM and drives the K/V
     BlockSpec index_map, so each grid step DMAs exactly one page from HBM
-    into VMEM (the recv_unpack gather idiom). Online softmax over the
+    into VMEM (the dispatch_pack gather idiom). Online softmax over the
     split's pages accumulates in VMEM scratch (the flash_attention m/l/acc
     idiom); the split's locally-normalized output and its log-sum-exp are
     written at the last page.

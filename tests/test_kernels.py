@@ -17,6 +17,7 @@ from repro.kernels.fp8 import quantize_fp8 as qfp8_pallas
 from repro.kernels.fp8 import dequantize_fp8 as dqfp8_pallas
 from repro.kernels.grouped_gemm import grouped_gemm as gg_pallas
 from repro.kernels.recv_unpack import recv_unpack as ru_pallas
+from repro.kernels.recv_unpack import row_block as ru_row_block
 
 
 def tol(dt):
@@ -213,6 +214,137 @@ def test_recv_unpack_all_sentinel_and_cast():
     # out_dtype cast in copy mode
     got32 = ru_pallas(recv, gmap, out_dtype=jnp.float32, interpret=True)
     assert got32.dtype == jnp.float32
+
+
+def _recv_unpack_pair(recv, gmap, quant):
+    """(kernel, reference) unpack of f32 rows, fp8-quantized when quant."""
+    if quant:
+        q, s = ref.quantize_fp8(recv, 128)
+        return (ru_pallas(q, gmap, s, interpret=True), ref.recv_unpack(q, gmap, s))
+    return ru_pallas(recv, gmap, interpret=True), ref.recv_unpack(recv, gmap)
+
+
+def _assert_bitwise(got, want):
+    assert got.dtype == want.dtype and got.shape == want.shape
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(want, np.float32))
+
+
+@pytest.mark.parametrize("quant", [False, True])
+@pytest.mark.parametrize("R,H,shape", [
+    (64, 256, (5, 13)),     # 65 slots: four blocks of 16 and one of a single slot
+    (48, 128, (3, 33)),     # 99 slots: the last block mostly padding
+])
+def test_recv_unpack_ragged_tail(R, H, shape, quant):
+    """A slot count that is not a multiple of the row block: the padded
+    tail is sliced off and the rest matches the reference bitwise."""
+    rng = np.random.RandomState(15)
+    recv = jnp.asarray(rng.randn(R, H) * 3, jnp.float32)
+    gmap = jnp.asarray(rng.randint(0, R + 1, shape), jnp.int32)
+    assert np.prod(shape) % ru_row_block(np.prod(shape), H, 4, 4) != 0
+    _assert_bitwise(*_recv_unpack_pair(recv, gmap, quant))
+
+
+@pytest.mark.parametrize("quant", [False, True])
+def test_recv_unpack_sentinel_blocks(quant):
+    """Block 0 all sentinels, block 1 all real, blocks 2 and 3 alternating
+    real and sentinel slots (four blocks of the kernel's row block)."""
+    R, H = 40, 256
+    rng = np.random.RandomState(16)
+    recv = jnp.asarray(rng.randn(R, H) * 3, jnp.float32)
+    rb = ru_row_block(64, H, 4, 4)
+    assert rb == 16
+    m = rng.randint(0, R, 4 * rb)
+    m[:rb] = R
+    m[2 * rb::2] = R
+    gmap = jnp.asarray(m.reshape(4, rb), jnp.int32)
+    got, want = _recv_unpack_pair(recv, gmap, quant)
+    _assert_bitwise(got, want)
+    got = np.asarray(got, np.float32).reshape(4 * rb, H)
+    assert np.all(got[:rb] == 0) and np.all(got[2 * rb::2] == 0)
+    assert np.all(np.any(got[rb:2 * rb] != 0, axis=-1))
+
+
+@pytest.mark.parametrize("quant", [False, True])
+def test_recv_unpack_expert_major_map(quant):
+    """A [L, A] map built as the plan builds it: each expert's region holds
+    a prefix of routed rows (positions_by_dest), the rest sentinels."""
+    from repro.core import slots as S
+    L, A, R, H = 8, 48, 96, 128
+    rng = np.random.RandomState(17)
+    recv = jnp.asarray(rng.randn(R, H) * 3, jnp.float32)
+    dest = jnp.asarray(rng.randint(0, L, R), jnp.int32)
+    valid = jnp.asarray(rng.rand(R) < 0.8)
+    pos, counts = S.positions_by_dest(dest, L, valid)
+    gmap = S.build_gather_map(dest, pos, jnp.arange(R, dtype=jnp.int32), valid,
+                              L, A, sentinel=R)
+    g = np.asarray(gmap)
+    for e, c in enumerate(np.asarray(counts)):
+        assert np.all(g[e, :c] < R) and np.all(g[e, c:] == R)
+    _assert_bitwise(*_recv_unpack_pair(recv, gmap, quant))
+
+
+@pytest.mark.parametrize("quant", [False, True])
+@pytest.mark.parametrize("bad", [np.inf, -np.inf, np.nan])
+def test_recv_unpack_sentinel_beside_nonfinite(bad, quant):
+    """Rows of inf or NaN land in block 0; block 4 (slots 64-66) reuses
+    the same half of the double buffer with sentinels in those slots. The
+    sentinel slots come out exactly zero, the non-finite rows as the
+    reference has them."""
+    R, H = 16, 256
+    assert ru_row_block(96, H, 4, 4) == 16
+    rng = np.random.RandomState(18)
+    recv = rng.randn(R, H).astype(np.float32)
+    recv[[3, 7]] = bad
+    recv[5, ::3] = bad
+    m = rng.randint(0, R, 96)
+    m[:3] = [3, 7, 5]
+    m[64:67] = R                                      # same buffer slots, block 4
+    m[70::4] = R
+    gmap = jnp.asarray(m.reshape(3, 32), jnp.int32)
+    if quant:
+        # quantize finite rows, then plant the non-finite payload and scale
+        q, s = ref.quantize_fp8(jnp.asarray(np.nan_to_num(recv)), 128)
+        q, s = np.array(q), np.array(s)
+        q[[3, 7]] = np.float32(bad)
+        s[5] = np.float32(bad)
+        q, s = jnp.asarray(q), jnp.asarray(s)
+        got, want = (ru_pallas(q, gmap, s, interpret=True),
+                     ref.recv_unpack(q, gmap, s))
+    else:
+        got, want = _recv_unpack_pair(jnp.asarray(recv), gmap, False)
+    _assert_bitwise(got, want)
+    got = np.asarray(got, np.float32).reshape(96, H)
+    sent = m == R
+    assert np.all(got[sent] == 0)
+    assert not np.all(np.isfinite(got[:3]))
+
+
+def test_recv_unpack_dequant_full_width():
+    """fp8 dequant at the paper's hidden size H = 7168, a small slot map;
+    row 0 holds every one of the 256 fp8 codes (subnormals, -0, NaN)."""
+    R, H = 12, 7168
+    rng = np.random.RandomState(19)
+    x = jnp.asarray(rng.randn(R, H) * 4, jnp.float32)
+    q, s = ref.quantize_fp8(x, 128)
+    codes = np.tile(np.arange(256, dtype=np.uint8), H // 256)
+    q = q.at[0].set(jax.lax.bitcast_convert_type(jnp.asarray(codes), q.dtype))
+    m = rng.randint(0, R + 1, (2, 5))
+    m[0, 0] = 0
+    gmap = jnp.asarray(m, jnp.int32)
+    got = ru_pallas(q, gmap, s, interpret=True)
+    _assert_bitwise(got, ref.recv_unpack(q, gmap, s))
+    assert got.dtype == jnp.bfloat16
+
+
+@pytest.mark.parametrize("M,H,in_size,out_size,rb", [
+    (65536, 7168, 1, 2, 16),    # dsv3-ep-ht: fp8 in, bf16 out
+    (8192, 7168, 1, 2, 16),     # dsv3-ep-ll-4chip
+    (10, 7168, 1, 2, 10),       # fewer slots than a block
+    (65536, 32768, 1, 4, 8),    # f32 out: 16 would overflow the buffer
+])
+def test_recv_unpack_row_block_size(M, H, in_size, out_size, rb):
+    assert ru_row_block(M, H, in_size, out_size) == rb
 
 
 @pytest.mark.parametrize("M,H,block", [(8, 256, 128), (16, 512, 128), (8, 128, 128),

@@ -76,6 +76,15 @@ def test_recv_unpack(one_chip, quant):
     _compile(ru.recv_unpack, one_chip, *shapes)
 
 
+@pytest.mark.parametrize("R,gmap", [(32768, (256, 256)), (512, (64, 128))],
+                         ids=["ht", "ll"])
+def test_recv_unpack_cells(one_chip, R, gmap):
+    # the EP cells' dispatch-recv unpack: fp8 rows and their scales into a
+    # [L, A] expert-major map, HT on one chip, LL on each chip of four
+    _compile(ru.recv_unpack, one_chip,
+             ((R, H), F8), (gmap, I32), ((R, H // QB), F32))
+
+
 @pytest.mark.parametrize("R,T", [(32768, 4096), (4096, 128)], ids=["ht", "ll"])
 def test_combine_gather_reduce(one_chip, R, T):
     # the EP cells' combine: HT 4096 tokens x top-8 on one chip, LL 128
